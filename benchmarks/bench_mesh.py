@@ -9,9 +9,12 @@ schema's :class:`~repro.core.schema.ShardSpec` hints), so each device runs
 its slice of the same program.
 
 The measurement happens in a SUBPROCESS (``mesh_worker.py``) with
-``XLA_FLAGS=--xla_force_host_platform_device_count=4`` — the CI machine has
-no accelerators, so four fake host devices stand in for the mesh, exactly
-as the tests do.  The worker builds the same 3-stage matmul chain through
+``JAX_PLATFORMS=cpu`` and
+``XLA_FLAGS=--xla_force_host_platform_device_count=4``: the worker
+simulates a four-device mesh on the CPU, exactly as the tests do.  It never
+touches an accelerator, so it cannot contend with this process for a chip
+that an earlier benchmark already initialised.  The worker builds the
+same 3-stage matmul chain through
 the real DSL + fusion pass and reports sharded vs single-device-batched
 ``process_batch`` throughput plus bit-identity of both against the
 host-composed chain.
@@ -44,6 +47,7 @@ def run() -> dict:
         emit("mesh_sharded", 0.0, "skipped=no_jax")
         return {"skipped": "jax not importable"}
     env = dict(os.environ)
+    env["JAX_PLATFORMS"] = "cpu"
     env["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={DEVICES}"
     env["PYTHONPATH"] = str(_REPO / "src")
     env.pop("DATAX_FUSION_MESH", None)

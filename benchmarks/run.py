@@ -222,6 +222,13 @@ def main() -> int:
     ap.add_argument("--out-dir", default=".",
                     help="where BENCH_<name>.json artifacts are written")
     args = ap.parse_args()
+    try:
+        import jax  # noqa: F401
+    except Exception:
+        pass  # minimal-deps leg: nothing is compiled
+    else:
+        from repro.launch.compile_cache import enable_compile_cache
+        enable_compile_cache()
     out_dir = pathlib.Path(args.out_dir)
     out_dir.mkdir(parents=True, exist_ok=True)
     print("name,us_per_call,derived")

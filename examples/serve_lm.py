@@ -12,15 +12,21 @@ state locality that lets ``.scaled(instances=N)`` shard sessions across N
 engines without forking their state (this example keeps one engine so the
 jit compile is paid once).
 
+The engine reads its model from its stream configuration: ``arch`` names a
+published architecture (``""`` keeps the small built-in preset), ``layers``
+cuts its depth (0 keeps it), ``seed`` makes its random weights.
+``chip_smoke.py`` deploys this app with qwen3-14b at full width.
+
 Run:  PYTHONPATH=src python examples/serve_lm.py --requests 12 --slots 4
 """
 import argparse
 import dataclasses
+import functools
 import time
 
 import numpy as np
 
-from repro.configs import get_smoke_config
+from repro.configs import get_config, get_smoke_config
 from repro.configs.base import RunConfig
 from repro.core import (App, ConfigSchema, FieldSpec, StreamSchema, connect,
                         drain, sdk_entrypoint)
@@ -38,43 +44,68 @@ app = App("serve-lm")
 
 
 @app.driver(emits=REQUEST)
-def request_gen(ctx, requests=12, sessions=3, vocab=4096, seed=0):
+def request_gen(ctx, requests=12, sessions=3, vocab=4096, prompt_min=4,
+                prompt_max=24, max_new=16, seed=0):
     rng = np.random.default_rng(seed)
 
     def gen():
         for i in range(requests):
             if not ctx.running:
                 return
-            prompt = rng.integers(1, vocab, int(rng.integers(4, 24)),
-                                  dtype=np.int32)
+            plen = int(rng.integers(prompt_min, prompt_max))
+            prompt = rng.integers(1, vocab, plen, dtype=np.int32)
             yield {"request_id": f"req-{i:03d}",
                    "session": f"sess-{i % sessions}",
                    "prompt": prompt,
-                   "max_new": 16}
+                   "max_new": max_new}
     return gen()
+
+
+def make_model(arch: str = "", layers: int = 0, attention: str = "naive",
+               seed: int = 0):
+    """``(cfg, run, params)`` of the served model: the small built-in preset
+    when ``arch`` is empty, else ``arch`` at its published widths;
+    ``layers > 0`` cuts the depth.  Weights are random, from ``seed``."""
+    import jax
+
+    from repro import models
+
+    if arch:
+        cfg = get_config(arch)
+    else:
+        cfg = dataclasses.replace(
+            get_smoke_config("qwen3-14b"), n_layers=4, d_model=128,
+            n_heads=4, n_kv_heads=2, d_ff=512, vocab=4096, head_dim=32)
+    if layers:
+        cfg = dataclasses.replace(cfg, n_layers=layers)
+    run = RunConfig(attention_impl=attention, attention_chunk=256,
+                    remat="none")
+    # jitted, so each weight is drawn straight into its own dtype (eager
+    # init would hold a float32 copy of the largest matrices on the device)
+    init = jax.jit(functools.partial(models.init, cfg=cfg))
+    return cfg, run, init(jax.random.PRNGKey(seed))
 
 
 @app.analytics_unit(expects=(REQUEST,), emits=RESPONSE, stateful=True,
                     config=ConfigSchema.of(slots=("int", 4),
-                                           max_new=("int", 16)))
+                                           max_new=("int", 16),
+                                           max_seq=("int", 256),
+                                           arch=("str", ""),
+                                           layers=("int", 0),
+                                           attention=("str", "naive"),
+                                           seed=("int", 0)))
 @sdk_entrypoint
 def lm_engine(dx):
     """SDK-style engine: owns its loop, three-method SDK + platform db."""
-    import jax
-
-    from repro import models
     from repro.serve import ServeEngine
 
-    cfg = dataclasses.replace(
-        get_smoke_config("qwen3-14b"), n_layers=4, d_model=128, n_heads=4,
-        n_kv_heads=2, d_ff=512, vocab=4096, head_dim=32)
-    run = RunConfig(attention_impl="naive", remat="none")
-    params = models.init(jax.random.PRNGKey(0), cfg)
     conf = dx.get_configuration()
+    cfg, run, params = make_model(conf["arch"], conf["layers"],
+                                  conf["attention"], conf["seed"])
     # the KV slot table lives in the stream's platform database: an engine
     # restart — or a session re-homed by keyed rebalance — recovers its map
     engine = ServeEngine(cfg, run, params, n_slots=conf["slots"],
-                         max_seq=256, db=dx.db)
+                         max_seq=conf["max_seq"], db=dx.db)
     sessions: dict[str, str] = {}
     while dx.running:
         item = dx.next(timeout=0.02)
@@ -94,14 +125,20 @@ def lm_engine(dx):
                          "ttft_ms": (req.first_token_at - req.arrived) * 1e3})
 
 
-def build_app(requests=12, slots=4, max_new=16) -> App:
+def build_app(requests=12, slots=4, max_new=16, *, prompt=(4, 24),
+              **model) -> App:
     """Wire the serving topology (request driver -> session-keyed engine ->
     tapped responses) and return the app — also the entry point
-    ``datax check`` discovers."""
-    reqs = app.sense("requests", request_gen, requests=requests)
+    ``datax check`` discovers.  ``prompt`` is the [min, max) prompt length;
+    ``model`` holds engine settings (``arch``, ``layers``, ``max_seq``,
+    ``attention``, ``seed``), defaulting to the small preset."""
+    vocab = get_config(model["arch"]).vocab if model.get("arch") else 4096
+    reqs = app.sense("requests", request_gen, requests=requests, vocab=vocab,
+                     prompt_min=prompt[0], prompt_max=prompt[1],
+                     max_new=max_new)
     responses = (reqs.key_by("session")
                  .via(lm_engine, name="responses", slots=slots,
-                      max_new=max_new, fixed_instances=1))
+                      max_new=max_new, fixed_instances=1, **model))
     responses.tap()   # promised to external consumers (§3 reuse)
     return app
 

@@ -41,7 +41,9 @@ runtime.  It operates on the compiled v1 :class:`~.app.Application` spec graph
    execution, still with zero interior bus hops.  A payload-local problem
    (a single non-numeric message) falls back for that message only; the
    device program stays live (``device_fallbacks`` counts them in sidecar
-   metrics) — only a genuine trace failure demotes the unit permanently.
+   metrics) — only a genuine program failure demotes the unit permanently,
+   and never unseen: ``device_demotions`` counts it in sidecar metrics and
+   a warning names the failure.
 
 4. **Batched execution** — under backlog the Executor drains a mailbox
    burst and hands it to ``process_batch``: the whole burst is stacked
@@ -65,8 +67,9 @@ runtime.  It operates on the compiled v1 :class:`~.app.Application` spec graph
    :func:`repro.distributed.sharding.burst_spec` — so every device
    computes its slice of the burst.  vmap rows are independent, so the
    sharded path is bit-identical to the single-device batched program; any
-   indivisible burst (and any sharded-lowering failure) transparently
-   stays on / returns to the single-device path.  Two ride-alongs:
+   indivisible burst stays on the single-device path, and a sharded-program
+   failure retires the sharded program for the unit (``sharded_retired``
+   in sidecar metrics, plus a warning).  Two ride-alongs:
 
    * **device residency** — a segment whose exit feeds ANOTHER fused
      segment's entry emits its array fields as :class:`ResidentArray`
@@ -93,6 +96,7 @@ from __future__ import annotations
 
 import dataclasses
 import enum
+import logging
 import time
 from typing import Any, Callable, Iterable, Mapping, Sequence
 
@@ -108,6 +112,8 @@ try:  # the pass (host-composed path) must work without jax installed
     _HAS_JAX = True
 except Exception:  # pragma: no cover - exercised via monkeypatch in tests
     _HAS_JAX = False
+
+_log = logging.getLogger(__name__)
 
 #: When the fused unit uses the jitted device program vs the host-composed
 #: chain (both are single-microservice, zero interior bus hops):
@@ -638,10 +644,11 @@ def make_fused_logic(stages: Sequence[FusedStage],
     burst divides it), ``default_max_batch``, ``current_max_batch`` (the
     autotuned ceiling, present only when the stream declared no
     ``max_batch`` of its own) and a ``stats`` counter dict
-    (``device_fallbacks`` / ``batched_bursts`` / ``batched_msgs`` /
-    ``sharded_bursts`` / ``resident_links`` / ``mesh_devices`` /
-    ``max_batch_current``).  ``resident=True`` marks a segment whose exit
-    feeds another fused segment: its array outputs stay device-resident
+    (``device_fallbacks`` / ``device_demotions`` / ``batched_bursts`` /
+    ``batched_msgs`` / ``sharded_bursts`` / ``sharded_retired`` /
+    ``resident_links`` / ``mesh_devices`` / ``max_batch_current``).
+    ``resident=True`` marks a segment whose exit feeds another fused
+    segment: its array outputs stay device-resident
     (:class:`ResidentArray`) for the linked hop.
     """
 
@@ -699,9 +706,26 @@ def make_fused_logic(stages: Sequence[FusedStage],
                 "slow": 0,
                 "auto": max_batch is None and program is not None}
         stats = {"device_fallbacks": 0, "unstackable_bursts": 0,
-                 "batched_bursts": 0, "batched_msgs": 0,
-                 "sharded_bursts": 0, "resident_links": 0,
+                 "device_demotions": 0, "batched_bursts": 0,
+                 "batched_msgs": 0, "sharded_bursts": 0,
+                 "sharded_retired": 0, "resident_links": 0,
                  "mesh_devices": ndev, "max_batch_current": tune["cur"]}
+
+        # A failing device program must not become host execution unseen:
+        # each demotion / retirement is counted (sidecar metrics) and logged.
+        def demote(exc: Exception) -> None:
+            mode["device"] = False
+            stats["device_demotions"] += 1
+            _log.warning("fused unit %s: device program failed, running the "
+                         "host chain from now on: %r",
+                         stages[-1].stream_name, exc)
+
+        def retire_sharded(exc: Exception) -> None:
+            sprog["fn"] = None
+            stats["sharded_retired"] += 1
+            _log.warning("fused unit %s: mesh-sharded program failed, "
+                         "single-device bursts from now on: %r",
+                         stages[-1].stream_name, exc)
 
         def run_device(payload: dict) -> dict | None:
             dev, keep = program(_to_device(payload))
@@ -731,11 +755,11 @@ def make_fused_logic(stages: Sequence[FusedStage],
                         out, keep = program(dev)
                         return _from_device(out, payload) if bool(keep) \
                             else None
-                    except Exception:
-                        # genuine trace failure (impure/untraceable stage):
-                        # permanently drop to the host-composed chain (still
-                        # zero bus hops)
-                        mode["device"] = False
+                    except Exception as e:
+                        # genuine program failure (impure/untraceable stage,
+                        # device error): permanently drop to the host-composed
+                        # chain (still zero bus hops)
+                        demote(e)
             return host_one(stream, payload)
 
         def autotune(burst: int, drain_s: float) -> None:
@@ -794,16 +818,15 @@ def make_fused_logic(stages: Sequence[FusedStage],
                         if sharded is not None:
                             try:
                                 out, keep = sharded(dev)
-                            except Exception:
-                                # sharding-specific lowering failure: retire
-                                # the sharded program for this unit; the
-                                # single-device batched program stays live
-                                sprog["fn"] = sharded = None
+                            except Exception as e:
+                                # the single-device batched program stays live
+                                retire_sharded(e)
+                                sharded = None
                         if sharded is None:
                             out, keep = batched_program(dev)
                         keep = np.asarray(keep)
-                    except Exception:
-                        mode["device"] = False
+                    except Exception as e:
+                        demote(e)
                     else:
                         stats["batched_bursts"] += 1
                         stats["batched_msgs"] += len(payloads)
@@ -853,8 +876,8 @@ def make_fused_logic(stages: Sequence[FusedStage],
                         if sprog["fn"] is not None and canonical % ndev == 0:
                             try:
                                 sprog["fn"](dev)
-                            except Exception:
-                                sprog["fn"] = None
+                            except Exception as e:
+                                retire_sharded(e)
                 process.warmup = warmup
         return process
 

@@ -276,6 +276,7 @@ class Sidecar:
                 # the sidecar-level batches/batch_msgs above count every
                 # mailbox pull, including per-message degrades
                 "device_fallbacks": int(stats.get("device_fallbacks", 0)),
+                "device_demotions": int(stats.get("device_demotions", 0)),
                 "unstackable_bursts": int(stats.get("unstackable_bursts", 0)),
                 "batched_bursts": int(stats.get("batched_bursts", 0)),
                 "batched_msgs": int(stats.get("batched_msgs", 0)),
@@ -287,6 +288,7 @@ class Sidecar:
                 # the autotuned burst ceiling currently in force
                 "mesh_devices": int(stats.get("mesh_devices", 1)),
                 "sharded_bursts": int(stats.get("sharded_bursts", 0)),
+                "sharded_retired": int(stats.get("sharded_retired", 0)),
                 "resident_links": int(stats.get("resident_links", 0)),
                 "max_batch_current": int(stats.get("max_batch_current", 0)),
                 # durability surface: log catalogs per durable subject,

@@ -10,9 +10,14 @@ causal skipping.  TPU-native design decisions (vs. a CUDA port):
   and (block_k × head_dim) of K/V — MXU-aligned (multiples of 128 for f32
   lanes / 8 sublanes; head_dim up to 128 fits one register tile);
 * fully-masked causal blocks are skipped with @pl.when (no MXU work), which
-  halves the FLOPs of the naive full-matrix schedule.
+  halves the FLOPs of the naive full-matrix schedule;
+* heads go ahead of the sequence inside the wrapper, so every block's last
+  two dims are (rows, Dh) — the (8, 128) tiling Mosaic demands.  A block of
+  one head taken from a [.., S, H, Dh] array would put that head (1 of H)
+  second-minor, which the TPU compiler refuses.  The transposes are O(S)
+  against the kernel's O(S²) work.
 
-Layout: q [B, Sq, H, Dh]; k/v [B, Sk, KH, Dh]; H = KH·G.
+Layout: q [B, Sq, H, Dh]; k/v [B, Sk, KH, Dh]; H = KH·G (heads-major inside).
 Grid: (B, H, Sq/bq, Sk/bk); K/V index_map sends q-head h to kv-head h//G.
 """
 from __future__ import annotations
@@ -48,9 +53,9 @@ def _kernel(q_ref, k_ref, v_ref, o_ref, acc_ref, m_ref, l_ref, *,
 
     @pl.when(live)
     def _compute():
-        q = q_ref[0, :, 0, :].astype(jnp.float32) * scale     # [bq, Dh]
-        k = k_ref[0, :, 0, :].astype(jnp.float32)             # [bk, Dh]
-        v = v_ref[0, :, 0, :].astype(jnp.float32)
+        q = q_ref[0, 0].astype(jnp.float32) * scale          # [bq, Dh]
+        k = k_ref[0, 0].astype(jnp.float32)                  # [bk, Dh]
+        v = v_ref[0, 0].astype(jnp.float32)
         s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                                 preferred_element_type=jnp.float32)  # [bq,bk]
         qpos = q_start + jax.lax.broadcasted_iota(jnp.int32, (block_q,
@@ -62,13 +67,13 @@ def _kernel(q_ref, k_ref, v_ref, o_ref, acc_ref, m_ref, l_ref, *,
             invalid = jnp.logical_or(invalid, kpos > qpos)
         s = jnp.where(invalid, NEG_INF, s)
 
-        m_prev = m_ref[...]
+        m_prev = m_ref[...]                                  # [bq, 1]
         l_prev = l_ref[...]
-        m_new = jnp.maximum(m_prev, s.max(axis=1))
+        m_new = jnp.maximum(m_prev, s.max(axis=1, keepdims=True))
         alpha = jnp.exp(m_prev - m_new)
-        p = jnp.exp(s - m_new[:, None])
-        l_ref[...] = l_prev * alpha + p.sum(axis=1)
-        acc_ref[...] = (acc_ref[...] * alpha[:, None]
+        p = jnp.exp(s - m_new)
+        l_ref[...] = l_prev * alpha + p.sum(axis=1, keepdims=True)
+        acc_ref[...] = (acc_ref[...] * alpha
                         + jax.lax.dot_general(
                             p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
                             preferred_element_type=jnp.float32))
@@ -77,7 +82,7 @@ def _kernel(q_ref, k_ref, v_ref, o_ref, acc_ref, m_ref, l_ref, *,
     @pl.when(ik == nk - 1)
     def _finalize():
         l = jnp.maximum(l_ref[...], 1e-30)
-        o_ref[0, :, 0, :] = (acc_ref[...] / l[:, None]).astype(o_ref.dtype)
+        o_ref[0, 0] = (acc_ref[...] / l).astype(o_ref.dtype)
 
 
 @functools.partial(jax.jit, static_argnames=("causal", "block_q", "block_k",
@@ -94,11 +99,14 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
     block_k = min(block_k, Sk)
     nq = pl.cdiv(Sq, block_q)
     nk = pl.cdiv(Sk, block_k)
+    q = q.transpose(0, 2, 1, 3)                   # heads-major [B, H, S, Dh]
+    k = k.transpose(0, 2, 1, 3)
+    v = v.transpose(0, 2, 1, 3)
     if Sq % block_q:
-        q = jnp.pad(q, ((0, 0), (0, nq * block_q - Sq), (0, 0), (0, 0)))
+        q = jnp.pad(q, ((0, 0), (0, 0), (0, nq * block_q - Sq), (0, 0)))
     if Sk % block_k:
-        k = jnp.pad(k, ((0, 0), (0, nk * block_k - Sk), (0, 0), (0, 0)))
-        v = jnp.pad(v, ((0, 0), (0, nk * block_k - Sk), (0, 0), (0, 0)))
+        k = jnp.pad(k, ((0, 0), (0, 0), (0, nk * block_k - Sk), (0, 0)))
+        v = jnp.pad(v, ((0, 0), (0, 0), (0, nk * block_k - Sk), (0, 0)))
 
     kernel = functools.partial(_kernel, causal=causal, scale=scale,
                                block_q=block_q, block_k=block_k, seq_k=Sk)
@@ -106,21 +114,21 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
         kernel,
         grid=(B, H, nq, nk),
         in_specs=[
-            pl.BlockSpec((1, block_q, 1, Dh),
-                         lambda b, h, iq, ik: (b, iq, h, 0)),
-            pl.BlockSpec((1, block_k, 1, Dh),
-                         lambda b, h, iq, ik: (b, ik, h // G, 0)),
-            pl.BlockSpec((1, block_k, 1, Dh),
-                         lambda b, h, iq, ik: (b, ik, h // G, 0)),
+            pl.BlockSpec((1, 1, block_q, Dh),
+                         lambda b, h, iq, ik: (b, h, iq, 0)),
+            pl.BlockSpec((1, 1, block_k, Dh),
+                         lambda b, h, iq, ik: (b, h // G, ik, 0)),
+            pl.BlockSpec((1, 1, block_k, Dh),
+                         lambda b, h, iq, ik: (b, h // G, ik, 0)),
         ],
-        out_specs=pl.BlockSpec((1, block_q, 1, Dh),
-                               lambda b, h, iq, ik: (b, iq, h, 0)),
-        out_shape=jax.ShapeDtypeStruct((B, nq * block_q, H, Dh), q.dtype),
+        out_specs=pl.BlockSpec((1, 1, block_q, Dh),
+                               lambda b, h, iq, ik: (b, h, iq, 0)),
+        out_shape=jax.ShapeDtypeStruct((B, H, nq * block_q, Dh), q.dtype),
         scratch_shapes=[
             pltpu.VMEM((block_q, Dh), jnp.float32),
-            pltpu.VMEM((block_q,), jnp.float32),
-            pltpu.VMEM((block_q,), jnp.float32),
+            pltpu.VMEM((block_q, 1), jnp.float32),
+            pltpu.VMEM((block_q, 1), jnp.float32),
         ],
         interpret=interpret,
     )(q, k, v)
-    return out[:, :Sq]
+    return out[:, :, :Sq].transpose(0, 2, 1, 3)

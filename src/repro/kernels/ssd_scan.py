@@ -6,10 +6,14 @@ matmul fragments; here the unit of work is a VMEM-resident chunk):
 * grid (B, H/bh, L/Q) — the innermost dimension walks chunks IN ORDER; the
   running inter-chunk state S [bh, N, P] lives in VMEM scratch, making the
   sequential-grid recurrence the inter-chunk scan (no cross-core sync);
-* per step, the quadratic intra-chunk term runs on the MXU:
-  (C·Bᵀ ⊙ decay) @ (dt·x), with Q×Q attention-like scores per head-block;
-* B/C are per-group (GVA); the group tile is broadcast across the head
-  block, so head-blocks never re-read B/C from HBM.
+* per step and head, the quadratic intra-chunk term runs on the MXU:
+  (C·Bᵀ ⊙ decay) @ (dt·x), with Q×Q attention-like scores;
+* B/C are per-group (GVA); the group tile is shared by the head block, so
+  head-blocks never re-read B/C from HBM;
+* the wrapper lays every operand out heads-major and precomputes the
+  elementwise terms (dt·x and the within-chunk cumulative log-decay), so
+  each block's last two dims are (Q, P), (bh, Q) or (Q, N) — the (8, 128)
+  tiling Mosaic demands — and the kernel body is 2-D matmuls per head.
 
 Layouts: x [B, L, H, P]; dt [B, L, H]; A [H]; Bm/Cm [B, L, G, N] with G=1
 (the assigned configs all use a single B/C group).
@@ -24,9 +28,13 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+# the state recurrence is specified in float32: contract at float32 on the
+# MXU too (Mosaic's default contracts float32 operands at lower precision)
+_F32 = jax.lax.Precision.HIGHEST
 
-def _kernel(x_ref, dt_ref, a_ref, b_ref, c_ref, y_ref, fs_ref, s_ref, *,
-            chunk: int, seq_len: int):
+
+def _kernel(xdt_ref, cum_ref, b_ref, c_ref, y_ref, fs_ref, s_ref, *,
+            block_h: int):
     ci = pl.program_id(2)
     nc = pl.num_programs(2)
 
@@ -34,62 +42,46 @@ def _kernel(x_ref, dt_ref, a_ref, b_ref, c_ref, y_ref, fs_ref, s_ref, *,
     def _init():
         s_ref[...] = jnp.zeros_like(s_ref)
 
-    x = x_ref[0, :, :, :].astype(jnp.float32)        # [Q, bh, P]
-    dt = dt_ref[0, :, :].astype(jnp.float32)         # [Q, bh]
-    A = a_ref[:].astype(jnp.float32)                 # [bh]
-    Bm = b_ref[0, :, 0, :].astype(jnp.float32)       # [Q, N]
-    Cm = c_ref[0, :, 0, :].astype(jnp.float32)       # [Q, N]
-
-    # zero padded tail positions (seq_len may not divide by chunk)
-    pos = ci * chunk + jax.lax.broadcasted_iota(jnp.int32, dt.shape, 0)
-    dt = jnp.where(pos < seq_len, dt, 0.0)           # a=exp(0)=1, xdt=0
-
-    dA = dt * A[None, :]                             # [Q, bh] (negative)
-    cum = jnp.cumsum(dA, axis=0)
-    seg = cum[-1, :]                                 # [bh]
-    xdt = x * dt[:, :, None]                         # [Q, bh, P]
-
-    # ---- intra-chunk: per head-block MXU matmuls --------------------------
-    cb = jax.lax.dot_general(Cm, Bm, (((1,), (1,)), ((), ())),
-                             preferred_element_type=jnp.float32)  # [Q, Q]
-    li = cum[:, None, :]                             # [Q, 1, bh]
-    lj = cum[None, :, :]                             # [1, Q, bh]
+    Bm = b_ref[0].astype(jnp.float32)                # [Q, N]
+    Cm = c_ref[0].astype(jnp.float32)                # [Q, N]
+    cum = cum_ref[0]                                 # [bh, Q] log-decay
+    cum_t = cum.T                                    # [Q, bh]
+    chunk = Bm.shape[0]
     iq = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 0)
     jq = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 1)
-    tril = (iq >= jq)[:, :, None]
-    # min-clamp is exact for valid (i>=j) entries and prevents exp overflow
-    # on masked ones (see models/mamba2.py)
-    M = jnp.where(tril, cb[:, :, None] * jnp.exp(jnp.minimum(li - lj, 0.0)),
-                  0.0)                               # [Q, Q, bh]
-    # y_intra[i,h,p] = Σ_j M[i,j,h]·xdt[j,h,p]  — batched over h on the MXU
-    y_intra = jax.lax.dot_general(
-        M.transpose(2, 0, 1), xdt.transpose(1, 0, 2),
-        (((2,), (1,)), ((0,), (0,))),
-        preferred_element_type=jnp.float32)          # [bh, Q, P]
+    tril = iq >= jq
+    last = jax.lax.broadcasted_iota(jnp.int32, (1, chunk), 1) == chunk - 1
+    cb = jax.lax.dot_general(Cm, Bm, (((1,), (1,)), ((), ())), precision=_F32,
+                             preferred_element_type=jnp.float32)  # [Q, Q]
 
-    # ---- inter-chunk: contribution of the carried state --------------------
-    S_prev = s_ref[...]                              # [bh, N, P]
-    # y_inter[i,h,p] = Σ_n C[i,n]·S_prev[h,n,p]·exp(cum[i,h])
-    y_inter = jax.lax.dot_general(
-        Cm, S_prev, (((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32)          # [Q, bh, P]
-    y_inter = y_inter * jnp.exp(cum)[:, :, None]
-    y = y_intra.transpose(1, 0, 2) + y_inter
-    y_ref[0, :, :, :] = y.astype(y_ref.dtype)
-
-    # ---- state update -------------------------------------------------------
-    # S_c[h,n,p] = Σ_j B[j,n]·xdt[j,h,p]·exp(seg[h]-cum[j,h])
-    w = jnp.exp(seg[None, :] - cum)                  # [Q, bh]
-    xw = xdt * w[:, :, None]                         # [Q, bh, P]
-    S_c = jax.lax.dot_general(
-        Bm, xw, (((0,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32)          # [N, bh, P]
-    s_ref[...] = (S_prev * jnp.exp(seg)[:, None, None]
-                  + S_c.transpose(1, 0, 2))
+    for h in range(block_h):
+        xdt = xdt_ref[0, h]                          # [Q, P] (dt·x, f32)
+        li = cum_t[:, h:h + 1]                       # [Q, 1]
+        lj = cum[h:h + 1, :]                         # [1, Q]
+        seg = jnp.sum(jnp.where(last, lj, 0.0), axis=1,
+                      keepdims=True)                 # [1, 1] chunk total
+        # ---- intra-chunk: min-clamp is exact for valid (i>=j) entries and
+        # prevents exp overflow on masked ones (see models/mamba2.py)
+        M = jnp.where(tril, cb * jnp.exp(jnp.minimum(li - lj, 0.0)), 0.0)
+        y = jax.lax.dot_general(M, xdt, (((1,), (0,)), ((), ())),
+                                precision=_F32,
+                                preferred_element_type=jnp.float32)
+        # ---- inter-chunk: y[i,p] += Σ_n C[i,n]·S_prev[n,p]·exp(cum[i])
+        S_prev = s_ref[h]                            # [N, P]
+        y = y + jax.lax.dot_general(
+            Cm, S_prev, (((1,), (0,)), ((), ())), precision=_F32,
+            preferred_element_type=jnp.float32) * jnp.exp(li)
+        y_ref[0, h] = y.astype(y_ref.dtype)
+        # ---- state update: S_c[n,p] = Σ_j B[j,n]·xdt[j,p]·exp(seg-cum[j])
+        xw = xdt * jnp.exp(seg - li)                 # [Q, P]
+        S_c = jax.lax.dot_general(Bm, xw, (((0,), (0,)), ((), ())),
+                                  precision=_F32,
+                                  preferred_element_type=jnp.float32)
+        s_ref[h] = S_prev * jnp.exp(seg) + S_c
 
     @pl.when(ci == nc - 1)
     def _emit_state():
-        fs_ref[0, :, :, :] = s_ref[...]
+        fs_ref[0] = s_ref[...]
 
 
 @functools.partial(jax.jit,
@@ -106,35 +98,39 @@ def ssd_scan(x: jax.Array, dt: jax.Array, A: jax.Array, Bm: jax.Array,
     nc = pl.cdiv(L, chunk)
     nh = pl.cdiv(H, block_h)
     Lp = nc * chunk
-    if Lp != L:
-        pad = Lp - L
-        x = jnp.pad(x, ((0, 0), (0, pad), (0, 0), (0, 0)))
-        dt = jnp.pad(dt, ((0, 0), (0, pad), (0, 0)))
-        Bm = jnp.pad(Bm, ((0, 0), (0, pad), (0, 0), (0, 0)))
-        Cm = jnp.pad(Cm, ((0, 0), (0, pad), (0, 0), (0, 0)))
+    f32 = jnp.float32
+    dt = dt.astype(f32)
+    # padded tail positions get dt=0: decay exp(0)=1 and dt·x=0
+    pad = ((0, 0), (0, Lp - L))
+    xdt = jnp.pad(x.astype(f32) * dt[..., None], pad + ((0, 0), (0, 0)))
+    dA = jnp.pad(dt * A.astype(f32), pad + ((0, 0),))
+    cum = jnp.cumsum(dA.reshape(B, nc, chunk, H), axis=2)  # within-chunk
+    cum = cum.reshape(B, Lp, H).transpose(0, 2, 1)           # [B, H, Lp]
+    xdt = xdt.transpose(0, 2, 1, 3)                          # [B, H, Lp, P]
+    Bm = jnp.pad(Bm[:, :, 0], pad + ((0, 0),))               # [B, Lp, N]
+    Cm = jnp.pad(Cm[:, :, 0], pad + ((0, 0),))
 
-    kernel = functools.partial(_kernel, chunk=chunk, seq_len=L)
+    kernel = functools.partial(_kernel, block_h=block_h)
     y, fs = pl.pallas_call(
         kernel,
         grid=(B, nh, nc),
         in_specs=[
-            pl.BlockSpec((1, chunk, block_h, P),
-                         lambda b, h, c: (b, c, h, 0)),
-            pl.BlockSpec((1, chunk, block_h), lambda b, h, c: (b, c, h)),
-            pl.BlockSpec((block_h,), lambda b, h, c: (h,)),
-            pl.BlockSpec((1, chunk, 1, N), lambda b, h, c: (b, c, 0, 0)),
-            pl.BlockSpec((1, chunk, 1, N), lambda b, h, c: (b, c, 0, 0)),
+            pl.BlockSpec((1, block_h, chunk, P),
+                         lambda b, h, c: (b, h, c, 0)),
+            pl.BlockSpec((1, block_h, chunk), lambda b, h, c: (b, h, c)),
+            pl.BlockSpec((1, chunk, N), lambda b, h, c: (b, c, 0)),
+            pl.BlockSpec((1, chunk, N), lambda b, h, c: (b, c, 0)),
         ],
         out_specs=[
-            pl.BlockSpec((1, chunk, block_h, P),
-                         lambda b, h, c: (b, c, h, 0)),
+            pl.BlockSpec((1, block_h, chunk, P),
+                         lambda b, h, c: (b, h, c, 0)),
             pl.BlockSpec((1, block_h, N, P), lambda b, h, c: (b, h, 0, 0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((B, Lp, H, P), x.dtype),
+            jax.ShapeDtypeStruct((B, H, Lp, P), x.dtype),
             jax.ShapeDtypeStruct((B, H, N, P), jnp.float32),
         ],
         scratch_shapes=[pltpu.VMEM((block_h, N, P), jnp.float32)],
         interpret=interpret,
-    )(x, dt, A, Bm, Cm)
-    return y[:, :L], fs
+    )(xdt, cum, Bm, Cm)
+    return y[:, :, :L].transpose(0, 2, 1, 3), fs

@@ -13,21 +13,30 @@ Mesh shapes (TPU v5e pods):
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
+
+
+def _auto_mesh(shape: tuple, axes: tuple):
+    # Auto axes: the model code pins activations with
+    # with_sharding_constraint, which refuses Explicit axes (make_mesh's
+    # default)
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return _auto_mesh(shape, axes)
 
 
 def make_host_mesh(data: int = 1, model: int = 1):
-    """Small mesh over however many (host) devices exist — tests/examples."""
+    """``(data, model)`` mesh over the visible devices, for serving,
+    training and tests."""
     n = len(jax.devices())
     if data * model > n:
         raise ValueError(f"mesh {data}x{model} needs {data*model} devices, "
                          f"have {n}")
-    return jax.make_mesh((data, model), ("data", "model"))
+    return _auto_mesh((data, model), ("data", "model"))
 
 
 # Hardware constants for roofline (TPU v5e per chip)

@@ -19,6 +19,8 @@ def main() -> None:
     ap.add_argument("--max-new", type=int, default=16)
     args = ap.parse_args()
 
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     import jax
     import numpy as np
 

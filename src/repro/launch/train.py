@@ -29,6 +29,8 @@ def main() -> None:
                     choices=["none", "bf16", "int8_ef"])
     args = ap.parse_args()
 
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     if os.environ.get("JAX_COORDINATOR"):  # multi-host entry
         import jax
         jax.distributed.initialize()
@@ -37,12 +39,13 @@ def main() -> None:
 
     from repro.configs import get_config, get_smoke_config
     from repro.configs.base import RunConfig
+    from repro.launch.mesh import make_host_mesh
     from repro.train.trainer import Trainer, TrainerConfig
 
     cfg = get_config(args.arch) if args.full else get_smoke_config(args.arch)
     n_dev = len(jax.devices())
     data = args.data_axis or max(1, n_dev // args.model_axis)
-    mesh = jax.make_mesh((data, args.model_axis), ("data", "model"))
+    mesh = make_host_mesh(data, args.model_axis)
     run = RunConfig(attention_impl="chunked", attention_chunk=256,
                     remat="full" if args.full else "none",
                     microbatches=args.microbatches,

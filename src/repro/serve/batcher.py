@@ -25,6 +25,7 @@ class Request:
     # filled by the engine:
     slot: int | None = None
     generated: list = dataclasses.field(default_factory=list)
+    prefill_logits: Any = None   # [V] host logits of the last prompt token
     prefill_done: bool = False
     first_token_at: float | None = None
     finished_at: float | None = None

@@ -29,6 +29,7 @@ from repro import models
 from repro.configs.base import ModelConfig, RunConfig
 from repro.core.state import Database
 from repro.distributed.act_sharding import activation_mesh
+from repro.launch.mesh import make_host_mesh
 from repro.models import transformer as T
 
 from .batcher import ContinuousBatcher, Request
@@ -48,7 +49,7 @@ class ServeEngine:
                  db: Database | None = None, eos_id: int | None = None):
         self.cfg = cfg
         self.run = run
-        self.mesh = mesh or jax.make_mesh((1, 1), ("data", "model"))
+        self.mesh = mesh or make_host_mesh()
         self.n_slots = n_slots
         self.max_seq = max_seq
         self.eos_id = eos_id
@@ -146,7 +147,8 @@ class ServeEngine:
                 self.cache[name] = insert_kv(pool, piece, slot, plen)
             else:
                 self.cache[name] = insert_state(pool, piece, slot, plen)
-        first = int(np.asarray(logits)[0].argmax())
+        req.prefill_logits = np.asarray(logits)[0]
+        first = int(req.prefill_logits.argmax())
         req.generated.append(first)
         req.prefill_done = True
         req.first_token_at = time.monotonic()

@@ -29,6 +29,7 @@ from repro.core import (AnalyticsUnitSpec, DriverSpec, Operator, SensorSpec,
 from repro.data import corpus as corpus_mod
 from repro.data import pipeline as pipe
 from repro.distributed import sharding as shard
+from repro.launch.mesh import make_host_mesh
 
 from . import optimizer as opt
 from . import steps as steps_mod
@@ -59,7 +60,7 @@ class Trainer:
         self.cfg = cfg
         self.run = run
         self.tcfg = tcfg
-        self.mesh = mesh or jax.make_mesh((1, 1), ("data", "model"))
+        self.mesh = mesh or make_host_mesh()
         self.op = operator or Operator(reconcile_interval_s=0.2)
         self._own_operator = operator is None
         self.preemption = PreemptionHandler()

@@ -14,6 +14,7 @@ _NEEDS_JAX = [
     "test_property.py",
     "test_serve.py",
     "test_sharding.py",
+    "test_tpu_compile.py",
     "test_train.py",
 ]
 

@@ -365,6 +365,40 @@ def test_trace_failure_still_demotes_permanently(monkeypatch):
     assert all(np.array_equal(o["x"], good["x"] * 2.0) for o in out)
     assert proc.stats["batched_bursts"] == 0      # demoted: host chain now
     assert proc.stats["device_fallbacks"] == 0    # not a payload fallback
+    assert proc.stats["device_demotions"] == 1    # … but a counted demotion
+
+
+def test_device_demotion_is_logged_and_surfaced(monkeypatch, caplog):
+    """A failing device program never turns into host execution unseen: the
+    demotion is counted on the sidecar and logged as a warning."""
+    if not fusion.jax_available():
+        pytest.skip("device demotion needs jax")
+    monkeypatch.setenv("DATAX_FUSION_JIT", "always")
+    app = App("demotion-metrics")
+
+    @app.driver(emits=TEN)
+    def src(ctx, n=4):
+        return ({"x": np.full((8, 8), float(i), np.float32)}
+                for i in range(n))
+
+    (app.sense("raw", src)
+        .map(lambda p: {"x": p["x"] * (2.0 if float(p["x"].sum()) >= 0
+                                       else 1.0)},
+             emits=TEN, device=True, name="m1")
+        .map(lambda p: {"x": p["x"] + 1}, emits=TEN, device=True,
+             name="exit"))
+    with caplog.at_level("WARNING", logger="repro.core.fusion"):
+        with connect(start=False) as op:
+            app.deploy(op, start_sensors=False)
+            sub = op.subscribe("exit", maxsize=16)
+            op.start_pending_sensors()
+            out = [m.payload for m in drain(sub, 4, timeout=30)]
+            metrics = op.executor.instances_of("exit")[0].sidecar.metrics()
+    assert [float(o["x"][0, 0]) for o in out] == [1.0, 3.0, 5.0, 7.0]
+    assert metrics["device_demotions"] == 1       # exposed on the sidecar
+    assert metrics["device_fallbacks"] == 0       # not a payload fallback
+    assert any("device program failed" in r.getMessage()
+               for r in caplog.records)
 
 
 def test_ragged_burst_degrades_per_message_and_stays_device(monkeypatch):

@@ -70,7 +70,8 @@ def test_elastic_restore_new_sharding(tmp_path):
     mgr = CheckpointManager(str(tmp_path))
     state = _state()
     mgr.save(1, state, blocking=True)
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    from repro.launch.mesh import make_host_mesh
+    mesh = make_host_mesh()
     shardings = jax.tree.map(
         lambda _: NamedSharding(mesh, P()), state)
     restored, _ = mgr.restore(jax.eval_shape(lambda: state),

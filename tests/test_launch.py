@@ -55,6 +55,7 @@ def test_elastic_shrink_mesh_resumes_training(tmp_path):
         from repro.train import optimizer as opt, steps
         from repro.train.checkpoint import CheckpointManager
         from repro.train.fault import ElasticController
+        from repro.launch.mesh import make_host_mesh
 
         cfg = get_smoke_config("qwen3-14b")
         run = RunConfig(attention_impl="chunked", attention_chunk=16,
@@ -66,7 +67,7 @@ def test_elastic_shrink_mesh_resumes_training(tmp_path):
             lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype), batch)
 
         # phase 1: 4-device mesh
-        mesh4 = jax.make_mesh((4, 1), ("data", "model"))
+        mesh4 = make_host_mesh(4, 1)
         f4, _ = steps.jit_train_step(cfg, run, mesh4, bshape)
         params = models.init(key, cfg)
         state = opt.init_opt_state(params, run)
@@ -102,8 +103,9 @@ def test_hlo_collective_parse_multi_device():
         import jax, jax.numpy as jnp
         from jax.sharding import NamedSharding, PartitionSpec as P
         from repro.roofline.hlo_cost import analyze_hlo
+        from repro.launch.mesh import make_host_mesh
 
-        mesh = jax.make_mesh((1, 4), ("data", "model"))
+        mesh = make_host_mesh(1, 4)
         def f(x, w):
             return x @ w
         xs = NamedSharding(mesh, P(None, "model"))
@@ -120,3 +122,23 @@ def test_hlo_collective_parse_multi_device():
     res = _run(prog)
     assert res.returncode == 0, res.stderr[-3000:]
     assert "OK" in res.stdout
+
+
+def test_compile_cache_placed_from_outside_or_fixed_in_checkout(
+        monkeypatch, tmp_path):
+    """JAX_COMPILATION_CACHE_DIR wins and nothing is set in code; without it
+    the cache is the fixed checkout path (the path keys the cache)."""
+    import jax
+    from repro.launch.compile_cache import enable_compile_cache
+    before = jax.config.jax_compilation_cache_dir
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+    assert enable_compile_cache() == str(tmp_path)
+    assert jax.config.jax_compilation_cache_dir == before
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+    try:
+        path = enable_compile_cache()
+        assert path == os.path.join(_CWD, ".jax_cache")
+        assert jax.config.jax_compilation_cache_dir == path
+        assert enable_compile_cache() == path      # same path every call
+    finally:
+        jax.config.update("jax_compilation_cache_dir", before)

@@ -293,10 +293,39 @@ def test_declared_max_batch_disables_autotune(jit_always):
 # Metrics surface
 # ---------------------------------------------------------------------------
 
+def test_sharded_program_failure_is_retired_and_counted(jit_always,
+                                                       monkeypatch, caplog):
+    """A failing mesh-sharded program is retired with a count and a warning;
+    the burst still runs on the single-device batched program."""
+    from repro.kernels import ops
+
+    def failing(stages, mesh, specs=None):
+        def program(payload):
+            raise RuntimeError("forced sharded-program failure")
+        return program
+
+    monkeypatch.setattr(fusion, "fusion_mesh", _mesh1)
+    monkeypatch.setattr(ops, "jit_chain_sharded", failing)
+    proc = _fused_process(max_batch=8)
+    payloads = _payloads(8)
+    with caplog.at_level("WARNING", logger="repro.core.fusion"):
+        proc.warmup()
+        got = proc.process_batch("s", payloads)
+    assert proc.stats["sharded_retired"] == 1     # warmup retired it once
+    assert proc.stats["sharded_bursts"] == 0
+    assert proc.stats["batched_bursts"] == 1      # single-device still ran
+    assert proc.stats["device_demotions"] == 0
+    assert any("mesh-sharded program failed" in r.getMessage()
+               for r in caplog.records)
+    want = _fused_process(max_batch=8).process_batch("s", payloads)
+    for a, b in zip(got, want):
+        assert np.array_equal(a["x"], b["x"])
+
+
 def test_stats_carry_mesh_fields(jit_always):
     proc = _fused_process()
-    for key in ("sharded_bursts", "resident_links", "mesh_devices",
-                "max_batch_current"):
+    for key in ("sharded_bursts", "sharded_retired", "resident_links",
+                "mesh_devices", "max_batch_current", "device_demotions"):
         assert key in proc.stats
     assert proc.stats["mesh_devices"] == (fusion.fusion_mesh().size
                                           if fusion.fusion_mesh() else 1)

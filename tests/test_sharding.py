@@ -126,6 +126,7 @@ def test_multi_device_train_step_matches_single(tmp_path):
         from repro.configs.base import RunConfig
         from repro import models
         from repro.train import optimizer as opt, steps
+        from repro.launch.mesh import make_host_mesh
 
         cfg = get_smoke_config("qwen3-14b")
         run = RunConfig(attention_impl="chunked", attention_chunk=16,
@@ -141,7 +142,7 @@ def test_multi_device_train_step_matches_single(tmp_path):
         _, _, m1 = f1(params, opt_state, batch)
 
         # (2,2) mesh via the framework's sharding derivation
-        mesh = jax.make_mesh((2, 2), ("data", "model"))
+        mesh = make_host_mesh(2, 2)
         bshape = jax.tree.map(
             lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype), batch)
         f2, _ = steps.jit_train_step(cfg, run, mesh, bshape)
