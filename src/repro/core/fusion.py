@@ -97,7 +97,6 @@ from __future__ import annotations
 import dataclasses
 import enum
 import logging
-import time
 from typing import Any, Callable, Iterable, Mapping, Sequence
 
 import numpy as np
@@ -106,6 +105,7 @@ from .app import Application
 from .entities import AnalyticsUnitSpec, Placement, StreamSpec
 from .schema import StreamSchema
 from .sdk import BatchInterrupted, LogicContext, is_sdk_style
+from .spans import span
 
 try:  # the pass (host-composed path) must work without jax installed
     import jax  # noqa: F401
@@ -555,7 +555,9 @@ def _to_device_batched(payloads: Sequence[Mapping[str, Any]],
 
     Fields whose rows form an intact :class:`ResidentArray` burst skip the
     stack + transfer entirely and reuse the upstream device array
-    (counted in ``stats['resident_links']`` when a stats dict is given)."""
+    (counted in ``stats['resident_links']`` when a stats dict is given).
+    With ``stats``, the stack and the upload add their seconds to
+    ``stack_s`` and ``upload_s``."""
     import jax.numpy as jnp
     keys = payloads[0].keys()
     for p in payloads[1:]:
@@ -583,15 +585,19 @@ def _to_device_batched(payloads: Sequence[Mapping[str, Any]],
         if any(r.shape != first.shape or r.dtype != first.dtype
                for r in rows[1:]):
             raise TypeError(f"field {k!r}: ragged shapes/dtypes across burst")
-        if len(rows) < pad_to:
-            rows.extend(rows[-1:] * (pad_to - len(rows)))
-        out[k] = jnp.asarray(np.stack(rows))
+        with span("datax.fused.stack", stats, "stack_s"):
+            if len(rows) < pad_to:
+                rows.extend(rows[-1:] * (pad_to - len(rows)))
+            stacked = np.stack(rows)
+        with span("datax.fused.upload", stats, "upload_s"):
+            out[k] = jnp.asarray(stacked)
     return out
 
 
 def _from_device_batched(stacked: Mapping[str, Any],
                          likes: Sequence[Mapping[str, Any]],
-                         resident: bool = False) -> list[dict]:
+                         resident: bool = False,
+                         stats: dict | None = None) -> list[dict]:
     """Stacked device results -> one host payload per (unpadded) message.
 
     One device->host transfer per FIELD for the whole burst — that single
@@ -601,31 +607,35 @@ def _from_device_batched(stacked: Mapping[str, Any],
 
     With ``resident=True`` (segments feeding another fused segment) array
     rows come back as :class:`ResidentArray`, pinning the stacked device
-    result so the downstream unit can reuse it without re-uploading."""
-    host = {k: np.asarray(v) for k, v in stacked.items()}
+    result so the downstream unit can reuse it without re-uploading.
+    With ``stats``, the transfer and the per-row split add their seconds to
+    ``download_s`` and ``unstack_s``."""
+    with span("datax.fused.download", stats, "download_s"):
+        host = {k: np.asarray(v) for k, v in stacked.items()}
     outs = []
-    for i, like in enumerate(likes):
-        p = {}
-        for k, arr in host.items():
-            row = arr[i]
-            if row.ndim == 0:
-                src = like.get(k)
-                if src is not None and not isinstance(src, (np.ndarray,
-                                                            np.generic)):
-                    p[k] = row.item()
+    with span("datax.fused.unstack", stats, "unstack_s"):
+        for i, like in enumerate(likes):
+            p = {}
+            for k, arr in host.items():
+                row = arr[i]
+                if row.ndim == 0:
+                    src = like.get(k)
+                    if src is not None and not isinstance(
+                            src, (np.ndarray, np.generic)):
+                        p[k] = row.item()
+                    else:
+                        p[k] = row[()]
+                elif resident:
+                    # the copy below intentionally does NOT apply:
+                    # residency trades keeping the device burst alive for
+                    # a free re-entry on the linked hop
+                    p[k] = ResidentArray.wrap(np.array(row), stacked[k], i)
                 else:
-                    p[k] = row[()]
-            elif resident:
-                # the copy below intentionally does NOT apply: residency
-                # trades keeping the device burst alive for a free re-entry
-                # on the linked hop
-                p[k] = ResidentArray.wrap(np.array(row), stacked[k], i)
-            else:
-                # copy out of the stacked block: a view would keep the whole
-                # pad_to-sized burst alive for as long as ANY downstream
-                # consumer holds one message of it
-                p[k] = np.array(row)
-        outs.append(p)
+                    # copy out of the stacked block: a view would keep the
+                    # whole pad_to-sized burst alive for as long as ANY
+                    # downstream consumer holds one message of it
+                    p[k] = np.array(row)
+            outs.append(p)
     return outs
 
 
@@ -671,6 +681,10 @@ def make_fused_logic(stages: Sequence[FusedStage],
                 results.extend(host_chain(i + 1, stages[i].stream_name, p))
             return results
 
+        # host seconds per phase of a batched burst (the fused spans);
+        # the other counters join below
+        stats = {"stack_s": 0.0, "upload_s": 0.0, "place_s": 0.0,
+                 "program_s": 0.0, "download_s": 0.0, "unstack_s": 0.0}
         program = batched_program = mesh = None
         sprog = {"fn": None}  # sharded program; retired on lowering failure
         if jax_available() and _want_jit() \
@@ -694,7 +708,8 @@ def make_fused_logic(stages: Sequence[FusedStage],
                             # gate below only routes divisible bursts here
                             specs[fname] = burst_spec(
                                 mesh, mesh.size, f.shape, hints.get(fname))
-                sprog["fn"] = jit_chain_sharded(chain, mesh, specs)
+                sprog["fn"] = jit_chain_sharded(chain, mesh, specs,
+                                                stats=stats)
         ndev = mesh.size if mesh is not None else 1
         mode = {"device": program is not None}
         # device_fallbacks counts MESSAGES that ran on the host while the
@@ -705,11 +720,11 @@ def make_fused_logic(stages: Sequence[FusedStage],
         tune = {"cur": max_batch or DEFAULT_MAX_BATCH, "streak": 0,
                 "slow": 0,
                 "auto": max_batch is None and program is not None}
-        stats = {"device_fallbacks": 0, "unstackable_bursts": 0,
-                 "device_demotions": 0, "batched_bursts": 0,
-                 "batched_msgs": 0, "sharded_bursts": 0,
-                 "sharded_retired": 0, "resident_links": 0,
-                 "mesh_devices": ndev, "max_batch_current": tune["cur"]}
+        stats.update({"device_fallbacks": 0, "unstackable_bursts": 0,
+                      "device_demotions": 0, "batched_bursts": 0,
+                      "batched_msgs": 0, "sharded_bursts": 0,
+                      "sharded_retired": 0, "resident_links": 0,
+                      "mesh_devices": ndev, "max_batch_current": tune["cur"]})
 
         # A failing device program must not become host execution unseen:
         # each demotion / retirement is counted (sidecar metrics) and logged.
@@ -762,6 +777,11 @@ def make_fused_logic(stages: Sequence[FusedStage],
                         demote(e)
             return host_one(stream, payload)
 
+        def drained() -> float:
+            # a burst's drain is its stack, upload and program spans (the
+            # sharded program's placement lies inside its program span)
+            return stats["stack_s"] + stats["upload_s"] + stats["program_s"]
+
         def autotune(burst: int, drain_s: float) -> None:
             # occupancy feedback: a burst that fills the current ceiling
             # means the mailbox still had messages left behind; a streak of
@@ -801,7 +821,7 @@ def make_fused_logic(stages: Sequence[FusedStage],
             if mode["device"] and batched_program is not None \
                     and len(payloads) > 1:
                 pad_to = _round_up_pow2(len(payloads))
-                t0 = time.monotonic()
+                t0 = drained()
                 try:
                     dev = _to_device_batched(payloads, pad_to, stats)
                 except Exception:
@@ -815,16 +835,19 @@ def make_fused_logic(stages: Sequence[FusedStage],
                 else:
                     sharded = sprog["fn"] if pad_to % ndev == 0 else None
                     try:
-                        if sharded is not None:
-                            try:
-                                out, keep = sharded(dev)
-                            except Exception as e:
-                                # the single-device batched program stays live
-                                retire_sharded(e)
-                                sharded = None
-                        if sharded is None:
-                            out, keep = batched_program(dev)
-                        keep = np.asarray(keep)
+                        with span("datax.fused.program_wait", stats,
+                                  "program_s"):
+                            if sharded is not None:
+                                try:
+                                    out, keep = sharded(dev)
+                                except Exception as e:
+                                    # the single-device batched program
+                                    # stays live
+                                    retire_sharded(e)
+                                    sharded = None
+                            if sharded is None:
+                                out, keep = batched_program(dev)
+                            keep = np.asarray(keep)
                     except Exception as e:
                         demote(e)
                     else:
@@ -832,9 +855,10 @@ def make_fused_logic(stages: Sequence[FusedStage],
                         stats["batched_msgs"] += len(payloads)
                         if sharded is not None:
                             stats["sharded_bursts"] += 1
-                        autotune(len(payloads), time.monotonic() - t0)
+                        autotune(len(payloads), drained() - t0)
                         host = _from_device_batched(out, payloads,
-                                                    resident=resident)
+                                                    resident=resident,
+                                                    stats=stats)
                         return [host[i] if keep[i] else None
                                 for i in range(len(payloads))]
             # per-message fallback: a poison message here must not destroy
@@ -874,10 +898,13 @@ def make_fused_logic(stages: Sequence[FusedStage],
                         dev = _to_device_batched([zeros, zeros], canonical)
                         batched_program(dev)
                         if sprog["fn"] is not None and canonical % ndev == 0:
+                            placed = stats["place_s"]
                             try:
                                 sprog["fn"](dev)
                             except Exception as e:
                                 retire_sharded(e)
+                            # warm-up is set-up: only bursts count
+                            stats["place_s"] = placed
                 process.warmup = warmup
         return process
 

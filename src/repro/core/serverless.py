@@ -23,6 +23,7 @@ from .bus import BusLike, MessageBus
 from .delivery import DeliveryPolicy
 from .sdk import BatchInterrupted, DataX, LogicContext, is_sdk_style
 from .sidecar import Sidecar
+from .spans import span
 from .state import Database
 
 
@@ -207,14 +208,16 @@ class Executor:
         # only exposes the hook when the stream declared no ceiling.
         tuned = getattr(process, "current_max_batch", None) \
             if batch_fn is not None else None
+        timings = sidecar.timings
         def emit_outs(outs) -> None:
             if sink:
                 return
-            for out in outs:
-                if out is None:
-                    continue
-                for payload in (out if isinstance(out, list) else [out]):
-                    sidecar.emit(payload)
+            with span("datax.pump.emit", timings, "emit_s"):
+                for out in outs:
+                    if out is None:
+                        continue
+                    for payload in (out if isinstance(out, list) else [out]):
+                        sidecar.emit(payload)
 
         def account(t0: float, total: int, done: int) -> None:
             dt = (time.monotonic() - t0) / total
@@ -227,44 +230,48 @@ class Executor:
                     burst = max(1, int(tuned()))
                 except Exception:
                     tuned = None
-            if burst > 1:
-                got = sidecar.next_batch(burst, timeout=0.1)
-            else:
-                one = sidecar.next(timeout=0.1)
-                got = None if one is None else (one[0], [one[1]])
+            with span("datax.pump.pull", timings, "pull_s"):
+                if burst > 1:
+                    got = sidecar.next_batch(burst, timeout=0.1)
+                else:
+                    one = sidecar.next(timeout=0.1)
+                    got = None if one is None else (one[0], [one[1]])
             if got is None:
                 continue
             stream, msgs = got
-            t0 = time.monotonic()
-            try:
-                if len(msgs) == 1:
-                    if wants_headers:
-                        outs = [process(stream, msgs[0].payload,
-                                        headers=msgs[0].headers)]
+            # the burst's spans share the first message's seq
+            with span("datax.pump.burst", seq=msgs[0].seq):
+                t0 = time.monotonic()
+                try:
+                    if len(msgs) == 1:
+                        if wants_headers:
+                            outs = [process(stream, msgs[0].payload,
+                                            headers=msgs[0].headers)]
+                        else:
+                            outs = [process(stream, msgs[0].payload)]
                     else:
-                        outs = [process(stream, msgs[0].payload)]
-                else:
-                    outs = batch_fn(stream, [m.payload for m in msgs])
-            except BatchInterrupted as bi:
-                # a poison message partway through a burst: the successful
-                # prefix still flows downstream; only the poison and the
-                # never-processed tail die with this instance — and they are
-                # accounted, not silently vanished (the reconciler restarts
-                # the instance; a group survivor inherits the rest of the
-                # mailbox)
-                sidecar.note_lost(stream, len(msgs) - len(bi.results))
-                account(t0, len(msgs), len(bi.results))
-                emit_outs(bi.results)
-                raise
-            except Exception:
-                # poison message: the in-flight messages die with this
-                # instance and, under single delivery, the popped copies were
-                # the ONLY ones — account them on the subject's lost stat
-                sidecar.note_lost(stream, len(msgs))
-                account(t0, len(msgs), 0)
-                raise
-            account(t0, len(msgs), len(msgs))
-            emit_outs(outs)
+                        outs = batch_fn(stream, [m.payload for m in msgs])
+                except BatchInterrupted as bi:
+                    # a poison message partway through a burst: the
+                    # successful prefix still flows downstream; only the
+                    # poison and the never-processed tail die with this
+                    # instance — and they are accounted, not silently
+                    # vanished (the reconciler restarts the instance; a
+                    # group survivor inherits the rest of the mailbox)
+                    sidecar.note_lost(stream, len(msgs) - len(bi.results))
+                    account(t0, len(msgs), len(bi.results))
+                    emit_outs(bi.results)
+                    raise
+                except Exception:
+                    # poison message: the in-flight messages die with this
+                    # instance and, under single delivery, the popped copies
+                    # were the ONLY ones — account them on the subject's
+                    # lost stat
+                    sidecar.note_lost(stream, len(msgs))
+                    account(t0, len(msgs), 0)
+                    raise
+                account(t0, len(msgs), len(msgs))
+                emit_outs(outs)
 
     # ------------------------------------------------------------- lifecycle
     def stop_instance(self, instance_id: str) -> None:

@@ -22,6 +22,12 @@ from .delivery import DeliveryPolicy, ReplayFrom, policy_from_legacy
 from .schema import Message
 
 
+#: A fused unit's per-phase host seconds (``process.stats``), surfaced as
+#: top-level metrics; the pump's own are in :attr:`Sidecar.timings`.
+_PHASES = ("stack_s", "upload_s", "place_s", "program_s", "download_s",
+           "unstack_s")
+
+
 class Sidecar:
     """Connection + subscription + publish manager, with metrics.
 
@@ -80,6 +86,10 @@ class Sidecar:
         self.batches = 0              # next_batch() bursts handed out
         self.batch_msgs = 0           # messages delivered inside those bursts
         self.max_batch_seen = 0       # deepest single burst
+        # seconds the pump spent pulling and emitting (its spans), and the
+        # summed time messages waited between publish and being handed out
+        self.timings = {"pull_s": 0.0, "emit_s": 0.0,
+                        "mailbox_wait_s": 0.0, "mailbox_msgs": 0}
         self.started_at = time.monotonic()
         self.last_activity = self.started_at
         self._ewma_alpha = 0.2
@@ -102,8 +112,7 @@ class Sidecar:
             msgs = sub.next_batch(max_n, timeout=0)
             if msgs:
                 self._rr = (self._rr + i + 1) % n
-                self.last_activity = time.monotonic()
-                return (sub.subject, msgs)
+                return self._handed_out(sub.subject, msgs)
         if timeout == 0:
             return None
         sub = self._subs[self._rr % n]
@@ -111,8 +120,18 @@ class Sidecar:
         if not msgs:
             return None
         self._rr = (self._rr + 1) % n
-        self.last_activity = time.monotonic()
-        return (sub.subject, msgs)
+        return self._handed_out(sub.subject, msgs)
+
+    def _handed_out(self, subject: str, msgs: list) -> tuple[str, list]:
+        """Stamp activity and add each message's wait since publish
+        (``Message.ts``, the publisher's monotonic clock) to
+        ``mailbox_wait_s``."""
+        now = time.monotonic()
+        self.last_activity = now
+        t = self.timings
+        t["mailbox_wait_s"] += sum(now - m.ts for m in msgs)
+        t["mailbox_msgs"] += len(msgs)
+        return (subject, msgs)
 
     def next(self, timeout: float | None = 0.1) -> tuple[str, Message] | None:
         """Round-robin poll across input subscriptions.
@@ -280,6 +299,10 @@ class Sidecar:
                 "unstackable_bursts": int(stats.get("unstackable_bursts", 0)),
                 "batched_bursts": int(stats.get("batched_bursts", 0)),
                 "batched_msgs": int(stats.get("batched_msgs", 0)),
+                # host seconds per phase, summed over the unit's life (the
+                # spans of the pump and of fused units: docs/metrics.md)
+                **self.timings,
+                **{k: float(stats.get(k, 0.0)) for k in _PHASES},
                 # mesh execution surface (fused units on a multi-device
                 # mesh): how many devices the unit's mesh spans (1 = no
                 # mesh), how many bursts ran SPMD-partitioned across it,

@@ -95,7 +95,7 @@ def jit_chain_batched(stages):
     return jax.jit(jax.vmap(single))
 
 
-def jit_chain_sharded(stages, mesh, specs=None):
+def jit_chain_sharded(stages, mesh, specs=None, stats=None):
     """Mesh-partitioned variant of :func:`jit_chain_batched`.
 
     Same contract — ``program(stacked_payload) -> (stacked_payload,
@@ -112,17 +112,23 @@ def jit_chain_sharded(stages, mesh, specs=None):
     rows are independent; partitioning only changes which device computes
     a row).  The caller guarantees the leading dim divides the mesh's data
     axis — indivisible bursts must stay on the single-device program.
+    With ``stats`` (a fused unit's counter dict), the placement adds its
+    seconds to ``stats["place_s"]``.
     """
     from jax.sharding import NamedSharding, PartitionSpec
+
+    from ..core.spans import span
 
     batched = jit_chain_batched(stages)
     specs = dict(specs or {})
     default = PartitionSpec(mesh.axis_names[0])
 
     def program(payload):
-        placed = {
-            k: jax.device_put(v, NamedSharding(mesh, specs.get(k, default)))
-            for k, v in payload.items()}
+        with span("datax.fused.place", stats, "place_s"):
+            placed = {
+                k: jax.device_put(v, NamedSharding(mesh,
+                                                   specs.get(k, default)))
+                for k, v in payload.items()}
         return batched(placed)
 
     return program

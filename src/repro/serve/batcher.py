@@ -22,6 +22,7 @@ class Request:
     max_new_tokens: int = 32
     eos_id: int | None = None
     arrived: float = dataclasses.field(default_factory=time.monotonic)
+    admitted_at: float | None = None   # stamped when a slot is given
     # filled by the engine:
     slot: int | None = None
     generated: list = dataclasses.field(default_factory=list)
@@ -64,9 +65,11 @@ class ContinuousBatcher:
             self.completed.append(r)
         free = free_slots + len(finished)
         admit = []
+        now = time.monotonic()
         while self.waiting and free > 0 and \
                 len(admit) < self.max_prefill_per_tick:
             req = self.waiting.popleft()
+            req.admitted_at = now
             admit.append(req)
             free -= 1
         for r in admit:

@@ -27,6 +27,7 @@ import numpy as np
 
 from repro import models
 from repro.configs.base import ModelConfig, RunConfig
+from repro.core.spans import span
 from repro.core.state import Database
 from repro.distributed.act_sharding import activation_mesh
 from repro.launch.mesh import make_host_mesh
@@ -59,8 +60,11 @@ class ServeEngine:
         self.cache = models.init_cache(cfg, n_slots, max_seq)
         self.seq_lens = np.zeros((n_slots,), np.int32)
         self.last_token = np.zeros((n_slots,), np.int32)
+        # *_s: host seconds of the engine's spans (tick_s, device_wait_s:
+        # both syncs), and slot_wait_s: admission minus arrival, summed
         self.metrics = {"ticks": 0, "prefills": 0, "decode_steps": 0,
-                        "tokens_generated": 0}
+                        "tokens_generated": 0, "tick_s": 0.0,
+                        "device_wait_s": 0.0, "slot_wait_s": 0.0}
         self._decode = self._build_decode()
         self._prefill_cache: dict[int, Any] = {}
 
@@ -141,13 +145,15 @@ class ServeEngine:
         slot = self.slots.alloc(req.request_id)
         req.slot = slot
         insert_kv, insert_state = self._insert_fns
-        for name, pool in self.cache.items():
-            piece = small[name]
-            if name in ("k", "v", "xk", "xv"):
-                self.cache[name] = insert_kv(pool, piece, slot, plen)
-            else:
-                self.cache[name] = insert_state(pool, piece, slot, plen)
-        req.prefill_logits = np.asarray(logits)[0]
+        with span("datax.engine.insert"):
+            for name, pool in self.cache.items():
+                piece = small[name]
+                if name in ("k", "v", "xk", "xv"):
+                    self.cache[name] = insert_kv(pool, piece, slot, plen)
+                else:
+                    self.cache[name] = insert_state(pool, piece, slot, plen)
+        with span("datax.engine.prefill_wait", self.metrics, "device_wait_s"):
+            req.prefill_logits = np.asarray(logits)[0]
         first = int(req.prefill_logits.argmax())
         req.generated.append(first)
         req.prefill_done = True
@@ -166,7 +172,8 @@ class ServeEngine:
             "active": jnp.asarray(active),
         }
         next_tok, self.cache = self._decode(self.params, self.cache, batch)
-        next_tok = np.asarray(next_tok)
+        with span("datax.engine.decode_wait", self.metrics, "device_wait_s"):
+            next_tok = np.asarray(next_tok)
         for req in live:
             s = req.slot
             self.seq_lens[s] += 1
@@ -178,13 +185,18 @@ class ServeEngine:
 
     def tick(self) -> list[Request]:
         """One engine iteration; returns requests finished this tick."""
-        plan = self.batcher.plan_tick(self.slots.n_free)
-        for req in plan.finished:
-            self.slots.free(req.request_id)
-        for req in plan.admit:
-            self._do_prefill(req)
-        if plan.decode:
-            self._do_decode(plan.decode)
+        with span("datax.engine.tick", self.metrics, "tick_s"):
+            with span("datax.engine.plan"):
+                plan = self.batcher.plan_tick(self.slots.n_free)
+                for req in plan.finished:
+                    self.slots.free(req.request_id)
+            for req in plan.admit:
+                self.metrics["slot_wait_s"] += req.admitted_at - req.arrived
+                with span("datax.engine.prefill", request_id=req.request_id):
+                    self._do_prefill(req)
+            if plan.decode:
+                with span("datax.engine.decode"):
+                    self._do_decode(plan.decode)
         self.metrics["ticks"] += 1
         return plan.finished
 

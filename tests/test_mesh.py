@@ -299,7 +299,7 @@ def test_sharded_program_failure_is_retired_and_counted(jit_always,
     the burst still runs on the single-device batched program."""
     from repro.kernels import ops
 
-    def failing(stages, mesh, specs=None):
+    def failing(stages, mesh, specs=None, stats=None):
         def program(payload):
             raise RuntimeError("forced sharded-program failure")
         return program
