@@ -166,11 +166,10 @@ class RunConfig:
     expert_axis: str | None = None   # MoE expert-parallel axis (None = expert-TP)
     moe_group_size: int = 2048       # GShard expert-group size (dispatch is
                                      # O(S·C)=O(S²) per group -> smaller is cheaper)
-    decode_carry_cache: bool = False # thread KV cache through the layer-scan
-                                     # CARRY (guaranteed in-place) instead of
-                                     # xs->ys (which copies the full cache)
     decode_attn_impl: str = "direct" # direct | chunked (flash-decoding scan;
-                                     # never materializes [B,H,S] scores)
+                                     # never materializes [B,H,S] scores);
+                                     # the jnp decode read, where the Pallas
+                                     # kernel does not serve (a mesh, no TPU)
     grad_compression: str = "none"   # none | bf16 | int8_ef
     grad_accum_dtype: str = "float32"  # float32 | bfloat16 — microbatch grad
                                      # accumulator (bf16 halves grad-AR wire)

@@ -26,10 +26,21 @@ def flash_attention(q, k, v, *, causal: bool = True, block_q: int = 128,
                                block_k=block_k, interpret=_auto(interpret))
 
 
-def decode_attention(q, k_cache, v_cache, lens, *, block_s: int = 512,
+def decode_attention(q, k_cache, v_cache, lens, *, block_s: int = 256,
                      interpret: bool | None = None):
     return _da.decode_attention(q, k_cache, v_cache, lens, block_s=block_s,
                                 interpret=_auto(interpret))
+
+
+def decode_attention_stacked(q, k_all, v_all, lens, layer, *,
+                             block_s: int = 256,
+                             interpret: bool | None = None):
+    return _da.decode_attention_stacked(q, k_all, v_all, lens, layer,
+                                        block_s=block_s,
+                                        interpret=_auto(interpret))
+
+
+stacked_fits = _da.stacked_fits
 
 
 def ssd_scan(x, dt, A, Bm, Cm, *, chunk: int = 128, block_h: int = 8,

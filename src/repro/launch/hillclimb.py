@@ -67,9 +67,6 @@ VARIANTS = {
     },
     "C": {
         "baseline": {},
-        "carry_cache": {"decode_carry_cache": True},
-        "carry_noseqshard": {"decode_carry_cache": True,
-                             "seq_shard_kv": False},
         "chunked_attn": {"decode_attn_impl": "chunked",
                          "attention_chunk": 2048},
         "chunked_attn_512": {"decode_attn_impl": "chunked",

@@ -27,7 +27,8 @@ import jax
 import jax.numpy as jnp
 
 from repro.configs.base import ModelConfig, RunConfig
-from repro.distributed.act_sharding import constrain
+from repro.distributed.act_sharding import constrain, current_mesh
+from repro.kernels import ops
 
 from . import layers as L
 from . import mamba2 as M
@@ -40,6 +41,27 @@ def _dec_attn(run: RunConfig):
         return functools.partial(L.decode_attention_chunked,
                                  chunk=run.attention_chunk)
     return L.decode_attention
+
+
+def _stacked_attn(run: RunConfig, cache_shape):
+    """Decode attention over layer l of the stacked cache [L, B, S, KH, Dh],
+    chosen by where the cache lives.  On a TPU that holds the whole cache
+    (no mesh, or a mesh of one device) the Pallas kernel reads the layer in
+    place.  Elsewhere — a mesh that splits the cache, whose softmax over a
+    split sequence GSPMD reduces, or off the TPU — the jnp read slices the
+    layer out first."""
+    mesh = current_mesh()
+    if mesh is None:
+        whole_on_tpu = jax.default_backend() == "tpu"
+    else:
+        whole_on_tpu = (mesh.size == 1
+                        and mesh.devices.flat[0].platform == "tpu")
+    if whole_on_tpu and ops.stacked_fits(cache_shape):
+        return lambda q, kc_all, vc_all, l, lens: ops.decode_attention_stacked(
+            q, kc_all, vc_all, lens, l, interpret=False)
+    attn = _dec_attn(run)
+    return lambda q, kc_all, vc_all, l, lens: attn(
+        q, kc_all[l], vc_all[l], lens[:, None])
 
 
 def _adtype(cfg: ModelConfig):
@@ -244,40 +266,25 @@ def decode_dense(params: dict, cache: dict, batch: dict, cfg: ModelConfig,
             h2 = L.mlp_apply(lp["mlp"], xn, cfg)
         return x + h2
 
-    if run.decode_carry_cache:
-        # OPT: thread the stacked cache through the scan CARRY.  With the
-        # xs->ys formulation XLA materializes a second full-size cache
-        # buffer (the stacked ys) — the whole KV cache is copied every
-        # decode step.  A loop carry is updated in place; only the new
-        # token's KV is written.  (EXPERIMENTS.md §Perf, cell C.)
-        def layer(carry, inputs):
-            x, kc_all, vc_all = carry              # [L, B, S, KH, Dh]
-            lp, l = inputs
-            xn = L.rmsnorm_apply(lp["ln1"], x, cfg.norm_eps)
-            q, k, v = L.attention_qkv(lp["attn"], xn, cfg, ang)
-            kc_all = _cache_insert_at_layer(kc_all, k, l, wpos)
-            vc_all = _cache_insert_at_layer(vc_all, v, l, wpos)
-            o = _dec_attn(run)(q[:, 0], kc_all[l], vc_all[l],
-                               seq_lens[:, None] + 1)
-            x = x + (o.reshape(B, 1, H * Dh) @ lp["attn"]["wo"])
-            return (_ffn(x, lp), kc_all, vc_all), None
+    # The stacked cache rides the layer scan's CARRY, which XLA updates in
+    # place: a step writes only the new token's row.  Taking it as xs and
+    # returning ys instead copies the whole cache every step.
+    attend = _stacked_attn(run, cache["k"].shape)
 
-        (x, k_new, v_new), _ = jax.lax.scan(
-            layer, (x, cache["k"], cache["v"]),
-            (params["layers"], jnp.arange(cfg.n_layers)))
-    else:
-        def layer(x, inputs):
-            lp, kc, vc = inputs                    # kc/vc: [B, S, KH, Dh]
-            xn = L.rmsnorm_apply(lp["ln1"], x, cfg.norm_eps)
-            q, k, v = L.attention_qkv(lp["attn"], xn, cfg, ang)
-            kc = _cache_insert(kc, k, wpos)
-            vc = _cache_insert(vc, v, wpos)
-            o = _dec_attn(run)(q[:, 0], kc, vc, seq_lens[:, None] + 1)
-            x = x + (o.reshape(B, 1, H * Dh) @ lp["attn"]["wo"])
-            return _ffn(x, lp), (kc, vc)
+    def layer(carry, inputs):
+        x, kc_all, vc_all = carry                  # [L, B, S, KH, Dh]
+        lp, l = inputs
+        xn = L.rmsnorm_apply(lp["ln1"], x, cfg.norm_eps)
+        q, k, v = L.attention_qkv(lp["attn"], xn, cfg, ang)
+        kc_all = _cache_insert_at_layer(kc_all, k, l, wpos)
+        vc_all = _cache_insert_at_layer(vc_all, v, l, wpos)
+        o = attend(q[:, 0], kc_all, vc_all, l, seq_lens + 1)
+        x = x + (o.reshape(B, 1, H * Dh) @ lp["attn"]["wo"])
+        return (_ffn(x, lp), kc_all, vc_all), None
 
-        x, (k_new, v_new) = jax.lax.scan(
-            layer, x, (params["layers"], cache["k"], cache["v"]))
+    (x, k_new, v_new), _ = jax.lax.scan(
+        layer, (x, cache["k"], cache["v"]),
+        (params["layers"], jnp.arange(cfg.n_layers)))
 
     x = L.rmsnorm_apply(params["final_norm"], x, cfg.norm_eps)
     logits = L.unembed_apply(params["embed"], x)[:, 0]

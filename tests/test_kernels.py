@@ -57,6 +57,32 @@ def test_decode_attention_sweep(B, S, H, KH, Dh, bs, dtype):
 
 
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+@pytest.mark.parametrize("L,S,H,KH,Dh,bs", [
+    (3, 64, 8, 2, 64, 16),                    # GQA, four blocks
+    (2, 32, 4, 1, 32, 32),                    # MQA, one block
+])
+def test_decode_attention_stacked_reads_each_layer(L, S, H, KH, Dh, bs,
+                                                   dtype):
+    """The stacked-cache kernel, read at each layer in place, against the
+    reference over that layer alone; lengths 1, a block edge, one past it
+    and the whole cache."""
+    k1, k2, k3 = jax.random.split(KEY, 3)
+    lens = jnp.asarray([1, bs, min(bs + 1, S), S], jnp.int32)
+    B = lens.shape[0]
+    q = _rand(k1, (B, H, Dh), dtype)
+    kc = _rand(k2, (L, B, S, KH, Dh), dtype)
+    vc = _rand(k3, (L, B, S, KH, Dh), dtype)
+    assert ops.stacked_fits(kc.shape, bs)
+    for layer in range(L):
+        out = ops.decode_attention_stacked(q, kc, vc, lens, jnp.int32(layer),
+                                           block_s=bs)
+        want = ref.decode_attention_ref(q, kc[layer], vc[layer], lens)
+        np.testing.assert_allclose(np.asarray(out, np.float32),
+                                   np.asarray(want, np.float32),
+                                   **_tol(dtype))
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
 @pytest.mark.parametrize("B,L,H,P,N,chunk,bh", [
     (1, 64, 8, 16, 16, 16, 4),
     (2, 100, 16, 32, 64, 32, 8),              # padding tail

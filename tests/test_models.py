@@ -123,3 +123,31 @@ def test_prefill_decode_consistency_dense():
         lg, cache = models.decode_step(params, cache, batch, cfg, run)
         outs.append(lg)
     np.testing.assert_allclose(jnp.stack(outs, 1), full, atol=2e-4, rtol=2e-3)
+
+
+def test_decode_writes_only_each_active_slots_row():
+    """One decode step changes the stacked cache at (l, b, seq_lens[b]) of
+    each active slot and nowhere else: slots at the first, a middle and the
+    last position, and an inactive slot, whose write is dropped."""
+    cfg = get_smoke_config("qwen3-14b")
+    params = models.init(KEY, cfg)
+    Bc, Sc = 4, 16
+    cache = models.init_cache(cfg, Bc, Sc)
+    ka, kb = jax.random.split(jax.random.PRNGKey(3))
+    cache = {"k": jax.random.normal(ka, cache["k"].shape).astype(
+                 cache["k"].dtype),
+             "v": jax.random.normal(kb, cache["v"].shape).astype(
+                 cache["v"].dtype)}
+    seq_lens = np.array([0, Sc // 2, Sc - 1, 5], np.int32)
+    active = np.array([True, True, True, False])
+    batch = {"tokens": jax.random.randint(KEY, (Bc, 1), 0, cfg.vocab),
+             "seq_lens": jnp.asarray(seq_lens), "active": jnp.asarray(active)}
+    _, new = jax.jit(lambda p, c, b: models.decode_step(p, c, b, cfg, RUN))(
+        params, cache, batch)
+    for name in ("k", "v"):
+        old_c, new_c = np.asarray(cache[name]), np.asarray(new[name])
+        changed = np.any(old_c != new_c, axis=(3, 4))        # [L, B, S]
+        want = np.zeros_like(changed)
+        for b in np.flatnonzero(active):
+            want[:, b, seq_lens[b]] = True
+        np.testing.assert_array_equal(changed, want, err_msg=name)

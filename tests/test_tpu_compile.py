@@ -13,6 +13,7 @@ process at a time may load the TPU library, and every test worker imports
 this module.
 """
 import os
+import re
 
 import pytest
 
@@ -92,3 +93,93 @@ def test_fused_batched_chain_compiles_for_v5e(one_chip):
     compiled = program.lower(burst).compile()
     out, keep = compiled.out_info
     assert out["x"].shape == (32, 480, 640) and keep.shape == (32,)
+
+
+def _top_level_ops(hlo: str):
+    """(op, result type) of every instruction outside a fusion's body, a
+    fusion named by the op at its root."""
+    comps, cur = {}, None
+    for line in hlo.splitlines():
+        m = re.match(r"(?:ENTRY )?%?([\w.\-]+) .*\{$", line)
+        if m and not line.startswith(" "):
+            cur = comps.setdefault(m.group(1), [])
+        elif cur is not None and line.startswith("  "):
+            cur.append(line.strip())
+    fused = {c for lines in comps.values() for line in lines
+             if " fusion(" in line
+             for c in re.findall(r"calls=%?([\w.\-]+)", line)}
+
+    def op_of(rhs):
+        m = re.search(r"\}? ([\w\-]+)\((%[\w.\-]+)?", rhs)
+        return (m.group(1), m.group(2)) if m else ("", None)
+
+    def root_op(comp):
+        """The root's op, looking through bitcasts to what they view."""
+        defs = {}
+        for line in comps[comp]:
+            lhs, _, rhs = line.partition(" = ")
+            defs[lhs.split()[-1]] = op_of(rhs)
+            if lhs.startswith("ROOT"):
+                root = lhs.split()[-1]
+        op, arg = defs[root]
+        while op == "bitcast" and arg in defs:
+            op, arg = defs[arg]
+        return op
+
+    for comp, lines in comps.items():
+        if comp in fused:
+            continue
+        for line in lines:
+            rhs = line.partition(" = ")[2]
+            op = op_of(rhs)[0]
+            if op == "fusion":
+                op = root_op(re.search(r"calls=%?([\w.\-]+)", rhs).group(1))
+            yield op, rhs.split("{", 1)[0]
+
+
+def test_dense_decode_step_reads_and_writes_cache_in_place(one_chip):
+    """qwen3-14b's decode step at its widths (2 layers, 32 slots, 2,048
+    positions, bf16 cache donated) copies no cache: no top-level copy,
+    dynamic-slice or dynamic-update-slice produces the stacked cache or one
+    layer of it.  Only the new token's rows are scattered in place, and the
+    Pallas kernel reads each layer where it lies."""
+    import dataclasses
+
+    import numpy as np
+    from jax.sharding import Mesh
+
+    from repro import models
+    from repro.configs import get_config
+    from repro.configs.base import RunConfig
+    from repro.distributed.act_sharding import activation_mesh
+
+    cfg = dataclasses.replace(get_config("qwen3-14b"), n_layers=2)
+    run = RunConfig(attention_impl="chunked", attention_chunk=256,
+                    remat="none")
+    B, S = 32, 2048
+    mesh = Mesh(np.array(one_chip._device_assignment).reshape(1, 1),
+                ("data", "model"))
+
+    def step(params, cache, batch):
+        with activation_mesh(mesh):
+            logits, cache = models.decode_step(params, cache, batch, cfg, run)
+        return jnp.argmax(logits, axis=-1), cache
+
+    def specs(tree):
+        return jax.tree.map(lambda a: _spec(one_chip, a.shape, a.dtype), tree)
+
+    params = specs(jax.eval_shape(lambda: models.init(jax.random.PRNGKey(0),
+                                                      cfg)))
+    cache = specs(jax.eval_shape(lambda: models.init_cache(cfg, B, S)))
+    batch = {"tokens": _spec(one_chip, (B, 1), jnp.int32),
+             "seq_lens": _spec(one_chip, (B,), jnp.int32),
+             "active": _spec(one_chip, (B,), jnp.bool_)}
+    hlo = jax.jit(step, donate_argnums=(1,)).lower(
+        params, cache, batch).compile().as_text()
+    whole = f"bf16[{cfg.n_layers},{B},{S},8,128]"
+    layer = (f"bf16[{B},{S},8,128]", f"bf16[1,{B},{S},8,128]")
+    copies = [(op, ty) for op, ty in _top_level_ops(hlo)
+              if op in ("copy", "dynamic-slice", "dynamic-update-slice")
+              and (ty == whole or ty in layer)]
+    assert not copies, copies
+    assert "tpu_custom_call" in hlo
