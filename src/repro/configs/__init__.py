@@ -24,6 +24,7 @@ _MODULES = {
     "granite-moe-3b-a800m": "granite_moe_3b",
     "mamba2-370m": "mamba2_370m",
     "zamba2-2.7b": "zamba2_2p7b",
+    "mellum2-12b-a2.5b": "mellum2_12b",
 }
 
 ARCHS = tuple(_MODULES)

@@ -25,6 +25,19 @@ class MoEConfig:
 
 
 @dataclasses.dataclass(frozen=True)
+class YarnConfig:
+    """YaRN rotary scaling (arXiv:2309.00071), as Hugging Face's
+    ``_compute_yarn_parameters`` computes it: NTK-by-parts frequencies and
+    cos/sin scaled by ``attention_factor``."""
+
+    factor: float = 16.0
+    original_max_position: int = 8192
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    attention_factor: float = 1.0
+
+
+@dataclasses.dataclass(frozen=True)
 class SSMConfig:
     """Mamba2 (SSD) hyper-parameters."""
 
@@ -58,6 +71,13 @@ class ModelConfig:
     rope_theta: float = 1e6
     mrope: bool = False               # Qwen2-VL M-RoPE (3-section t/h/w)
     mrope_sections: tuple = (16, 24, 24)
+    # mixed attention: the kinds of one period of layers, repeated through
+    # the stack ("sliding" attends to the last `sliding_window` positions,
+    # "full" to all); () means every layer is full.  `yarn` scales the
+    # rotary frequencies of the full layers only.
+    layer_pattern: tuple = ()
+    sliding_window: int = 0
+    yarn: YarnConfig | None = None
     tie_embeddings: bool = False
     norm_eps: float = 1e-6
     act: str = "silu"                 # silu => SwiGLU MLP; gelu => GeLU MLP
@@ -76,6 +96,11 @@ class ModelConfig:
     @property
     def resolved_head_dim(self) -> int:
         return self.head_dim or (self.d_model // self.n_heads)
+
+    @property
+    def layer_period(self) -> tuple:
+        """The kinds of one period of layers: ("full",) without a pattern."""
+        return self.layer_pattern or ("full",)
 
     @property
     def attn_free(self) -> bool:

@@ -55,6 +55,16 @@ def current_mesh() -> Mesh | None:
     return ctx["mesh"] if ctx else None
 
 
+def whole_on_one_tpu() -> bool:
+    """Whether the program being traced runs on one TPU that holds every
+    array whole: no mesh and a TPU backend, or a mesh of one TPU device.
+    Pallas kernels that read an array where it lies serve only there."""
+    mesh = current_mesh()
+    if mesh is None:
+        return jax.default_backend() == "tpu"
+    return mesh.size == 1 and mesh.devices.flat[0].platform == "tpu"
+
+
 def _resolve(logical, dim: int, ctx) -> object:
     mesh = ctx["mesh"]
     if logical is None:

@@ -10,6 +10,7 @@ import jax
 
 from . import decode_attention as _da
 from . import flash_attention as _fa
+from . import grouped_matmul as _gm
 from . import rmsnorm as _rn
 from . import ssd_scan as _ss
 
@@ -41,6 +42,12 @@ def decode_attention_stacked(q, k_all, v_all, lens, layer, *,
 
 
 stacked_fits = _da.stacked_fits
+
+
+def expert_gmm(lhs, rhs, group_sizes, layer, *,
+               interpret: bool | None = None):
+    return _gm.expert_gmm(lhs, rhs, group_sizes, layer,
+                          interpret=_auto(interpret))
 
 
 def ssd_scan(x, dt, A, Bm, Cm, *, chunk: int = 128, block_h: int = 8,

@@ -18,6 +18,7 @@ import math
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from repro.configs.base import ModelConfig
 from repro.distributed.act_sharding import constrain
@@ -47,11 +48,43 @@ def rmsnorm_apply(params: dict, x: jax.Array, eps: float = 1e-6) -> jax.Array:
 # Rotary position embeddings (RoPE + Qwen2-VL M-RoPE)
 # ---------------------------------------------------------------------------
 
-def rope_angles(positions: jax.Array, head_dim: int, theta: float) -> jax.Array:
-    """positions [..., S] -> angles [..., S, head_dim//2] (f32)."""
+def rope_angles(positions: jax.Array, head_dim: int, theta: float,
+                inv_freq=None) -> jax.Array:
+    """positions [..., S] -> angles [..., S, head_dim//2] (f32).
+    ``inv_freq`` replaces the default frequencies θ^(-2i/head_dim)."""
     half = head_dim // 2
-    inv_freq = 1.0 / (theta ** (jnp.arange(half, dtype=jnp.float32) / half))
+    if inv_freq is None:
+        inv_freq = 1.0 / (theta ** (jnp.arange(half, dtype=jnp.float32)
+                                    / half))
     return positions.astype(jnp.float32)[..., None] * inv_freq
+
+
+def yarn_inv_freq(head_dim: int, theta: float, yarn) -> np.ndarray:
+    """YaRN's NTK-by-parts frequencies [head_dim//2] (f32), as Hugging
+    Face's ``_compute_yarn_parameters`` computes them: dimensions that turn
+    fewer than ``beta_slow`` times over the original context are
+    interpolated by ``factor``, those that turn more than ``beta_fast``
+    times are kept, and a linear ramp blends the ones between.  Cos and sin
+    are scaled by ``yarn.attention_factor`` where they are applied."""
+    f32 = np.float32
+    dim = head_dim
+
+    def correction_dim(rotations):
+        return (dim * math.log(yarn.original_max_position
+                               / (rotations * 2 * math.pi))
+                / (2 * math.log(theta)))
+
+    low = max(math.floor(correction_dim(yarn.beta_fast)), 0)
+    high = min(math.ceil(correction_dim(yarn.beta_slow)), dim - 1)
+    if low == high:
+        high += 0.001
+    pos_freqs = f32(theta) ** (np.arange(0, dim, 2, dtype=f32) / f32(dim))
+    extrapolation = f32(1.0) / pos_freqs
+    interpolation = f32(1.0) / (f32(yarn.factor) * pos_freqs)
+    ramp = np.clip((np.arange(dim // 2, dtype=f32) - f32(low))
+                   / f32(high - low), 0, 1).astype(f32)
+    keep = f32(1) - ramp
+    return (interpolation * (1 - keep) + extrapolation * keep).astype(f32)
 
 
 def mrope_angles(positions: jax.Array, head_dim: int, theta: float,
@@ -76,14 +109,18 @@ def mrope_angles(positions: jax.Array, head_dim: int, theta: float,
     return jnp.concatenate(chunks, axis=-1)                 # [B, S, half]
 
 
-def apply_rope(x: jax.Array, angles: jax.Array) -> jax.Array:
-    """x: [B, S, H, Dh]; angles: [B, S, Dh//2] (broadcast over heads)."""
+def apply_rope(x: jax.Array, angles: jax.Array,
+               scale: float = 1.0) -> jax.Array:
+    """x: [B, S, H, Dh]; angles: [B, S, Dh//2] (broadcast over heads);
+    ``scale`` multiplies cos and sin (YaRN's attention factor)."""
     dtype = x.dtype
     x = x.astype(jnp.float32)
     half = x.shape[-1] // 2
     x1, x2 = x[..., :half], x[..., half:]
     cos = jnp.cos(angles)[..., None, :]   # [B, S, 1, half]
     sin = jnp.sin(angles)[..., None, :]
+    if scale != 1.0:
+        cos, sin = cos * scale, sin * scale
     out = jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1)
     return out.astype(dtype)
 
@@ -96,8 +133,10 @@ _NEG_INF = -2.0e30
 
 
 def naive_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
-                    causal: bool, q_offset: int = 0) -> jax.Array:
-    """Reference attention.  q: [B,Sq,H,Dh], k/v: [B,Sk,KH,Dh] with H=KH*G."""
+                    causal: bool, q_offset: int = 0,
+                    window: int = 0) -> jax.Array:
+    """Reference attention.  q: [B,Sq,H,Dh], k/v: [B,Sk,KH,Dh] with H=KH*G.
+    ``window`` > 0 (causal only): query i sees key j iff i - window < j."""
     B, Sq, H, Dh = q.shape
     KH = k.shape[2]
     G = H // KH
@@ -108,6 +147,8 @@ def naive_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
         qpos = q_offset + jnp.arange(Sq)
         kpos = jnp.arange(k.shape[1])
         mask = kpos[None, :] > qpos[:, None]                # [Sq, Sk]
+        if window:
+            mask = mask | (kpos[None, :] <= qpos[:, None] - window)
         s = jnp.where(mask[None, :, None, None, :], _NEG_INF, s)
     p = jax.nn.softmax(s, axis=-1)
     out = jnp.einsum("bqkgc,bckd->bqkgd", p, v.astype(jnp.float32))
@@ -116,11 +157,13 @@ def naive_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
 
 def chunked_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
                       causal: bool, chunk: int = 1024,
-                      q_offset: int = 0) -> jax.Array:
+                      q_offset: int = 0, window: int = 0) -> jax.Array:
     """Online-softmax attention over KV chunks (flash algorithm, pure JAX).
 
     Memory: O(Sq·H·Dh + Sq·H·chunk) instead of O(Sq·Sk·H).  This is the
     implementation the dry-run lowers — honest FLOPs, bounded temps.
+    ``window`` > 0 (causal only): query i sees key j iff i - window < j ≤ i;
+    every chunk is still visited, the keys outside the window masked.
     """
     B, Sq, H, Dh = q.shape
     Sk = k.shape[1]
@@ -147,6 +190,8 @@ def chunked_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
         invalid = kpos[None, :] >= Sk                       # padding
         if causal:
             invalid = invalid | (kpos[None, :] > qpos[:, None])
+        if window:
+            invalid = invalid | (kpos[None, :] <= qpos[:, None] - window)
         s = jnp.where(invalid[None, :, None, None, :], _NEG_INF, s)
         m_new = jnp.maximum(m, s.max(axis=-1))
         alpha = jnp.exp(m - m_new)
@@ -262,8 +307,9 @@ def init_attention(key: jax.Array, cfg: ModelConfig, dtype) -> dict:
 
 
 def attention_qkv(params: dict, x: jax.Array, cfg: ModelConfig,
-                  angles: jax.Array):
-    """Project + (qk-norm) + rope.  Returns q [B,S,H,Dh], k/v [B,S,KH,Dh]."""
+                  angles: jax.Array, rope_scale: float = 1.0):
+    """Project + (qk-norm) + rope.  Returns q [B,S,H,Dh], k/v [B,S,KH,Dh].
+    ``rope_scale`` multiplies rope's cos and sin (YaRN)."""
     B, S, _ = x.shape
     H, KH, Dh = cfg.n_heads, cfg.n_kv_heads, cfg.resolved_head_dim
     q = (x @ params["wq"]).reshape(B, S, H, Dh)
@@ -273,8 +319,8 @@ def attention_qkv(params: dict, x: jax.Array, cfg: ModelConfig,
         q = rmsnorm_apply(params["q_norm"], q, cfg.norm_eps)
         k = rmsnorm_apply(params["k_norm"], k, cfg.norm_eps)
     if angles is not None:
-        q = apply_rope(q, angles)
-        k = apply_rope(k, angles)
+        q = apply_rope(q, angles, rope_scale)
+        k = apply_rope(k, angles, rope_scale)
     # attention operates on the full sequence per head shard: under
     # sequence parallelism the seq dim is gathered at this boundary
     # (Megatron-style), so q/k/v pin heads but leave seq unsharded
@@ -287,20 +333,26 @@ def attention_qkv(params: dict, x: jax.Array, cfg: ModelConfig,
 def attention_apply(params: dict, x: jax.Array, cfg: ModelConfig, *,
                     angles: jax.Array | None, causal: bool = True,
                     impl: str = "chunked", chunk: int = 1024,
-                    kv_override: tuple | None = None) -> jax.Array:
-    """Full attention block on [B, S, D].  kv_override: cross-attention."""
+                    kv_override: tuple | None = None,
+                    rope_scale: float = 1.0, window: int = 0) -> jax.Array:
+    """Full attention block on [B, S, D].  kv_override: cross-attention;
+    ``window`` > 0: sliding-window attention (naive and chunked only)."""
     B, S, _ = x.shape
     H, Dh = cfg.n_heads, cfg.resolved_head_dim
-    q, k, v = attention_qkv(params, x, cfg, angles)
+    q, k, v = attention_qkv(params, x, cfg, angles, rope_scale)
     if kv_override is not None:
         k, v = kv_override
     if impl == "naive":
-        o = naive_attention(q, k, v, causal=causal)
+        o = naive_attention(q, k, v, causal=causal, window=window)
     elif impl == "pallas":
+        if window:
+            raise NotImplementedError("the Pallas flash kernel has no "
+                                      "sliding window")
         from repro.kernels import ops as kops
         o = kops.flash_attention(q, k, v, causal=causal)
     else:
-        o = chunked_attention(q, k, v, causal=causal, chunk=chunk)
+        o = chunked_attention(q, k, v, causal=causal, chunk=chunk,
+                              window=window)
     o = constrain(o, "batch", None, "heads", None)
     return o.reshape(B, S, H * Dh) @ params["wo"]
 

@@ -13,6 +13,14 @@ einsums lower to expert-parallel collectives when experts are sharded.
     combine  [G,S,E,C] (bf16, gate-scaled) · y -> out         (a2a/gather)
 
 Aux losses (Switch load-balance + router z-loss) are averaged over groups.
+Training takes this path (:func:`moe_apply`).
+
+Serving takes the dropless path (:func:`moe_dropless`): every valid token
+reaches all of its top-k experts.  Token-expert pairs are sorted by expert
+and each expert's rows go through one grouped matmul (the Pallas
+``expert_gmm`` on a TPU that holds the whole layer, ``lax.ragged_dot``
+elsewhere).  A validity mask sends pad tokens and inactive decode slots
+nowhere, so a prompt padded to its bucket routes exactly as unpadded.
 """
 from __future__ import annotations
 
@@ -22,7 +30,8 @@ import jax
 import jax.numpy as jnp
 
 from repro.configs.base import ModelConfig
-from repro.distributed.act_sharding import constrain
+from repro.distributed.act_sharding import constrain, whole_on_one_tpu
+from repro.kernels import ops
 
 
 def init_moe(key: jax.Array, cfg: ModelConfig, dtype) -> dict:
@@ -128,3 +137,70 @@ def moe_apply(params: dict, x: jax.Array, cfg: ModelConfig,
     aux = {"moe_load_balance": lb, "moe_z_loss": z,
            "moe_drop_fraction": dropped}
     return out.reshape(B, S, D), aux
+
+
+# ---------------------------------------------------------------------------
+# dropless serving path
+# ---------------------------------------------------------------------------
+
+# the counters one routed layer reports, in the order moe_dropless returns
+COUNTERS = ("moe_expert_tokens_max", "moe_routed_pairs", "moe_experts_hit",
+            "moe_dropped")
+EXPERT_WEIGHTS = ("w_gate", "w_up", "w_down")
+
+
+def _grouped_matmul():
+    """``(lhs, w [L, E, k, n], sizes, layer) -> f32``: the Pallas kernel,
+    reading layer ``layer`` of the stack in place, where one TPU holds it;
+    elsewhere (a mesh, no TPU) ``lax.ragged_dot`` on the sliced layer."""
+    if whole_on_one_tpu():
+        return lambda lhs, w, sizes, layer: ops.expert_gmm(
+            lhs, w, sizes, layer, interpret=False)
+    return lambda lhs, w, sizes, layer: jax.lax.ragged_dot(
+        lhs, w[layer], sizes, preferred_element_type=jnp.float32)
+
+
+def moe_dropless(params: dict, x: jax.Array, cfg: ModelConfig,
+                 valid: jax.Array | None = None, layer=None) -> tuple:
+    """x: [B, S, D], valid: [B, S] bool (None: every token) ->
+    (out [B, S, D], counters i32[4] in :data:`COUNTERS` order).
+
+    No capacity: each valid token's top-k pairs all reach their experts,
+    and invalid tokens route nowhere and come out zero.  With ``layer``,
+    the expert weights in ``params`` are the whole stack [L, E, ...] and
+    this layer's are read where they lie."""
+    m = cfg.moe
+    B, S, D = x.shape
+    T, E, K = B * S, m.num_experts, m.top_k
+    xt = x.reshape(T, D)
+    logits = xt.astype(jnp.float32) @ params["router"].astype(jnp.float32)
+    gates, experts = jax.lax.top_k(jax.nn.softmax(logits, axis=-1), K)
+    gates = gates / jnp.maximum(gates.sum(axis=-1, keepdims=True), 1e-9)
+    pair_ok = (jnp.ones((T, K), bool) if valid is None else
+               jnp.broadcast_to(valid.reshape(T, 1), (T, K))).reshape(-1)
+    key = jnp.where(pair_ok, experts.reshape(-1), E)    # invalid sort last
+    order = jnp.argsort(key, stable=True)               # pairs by expert
+    sizes = jnp.zeros((E + 1,), jnp.int32).at[key].add(1)[:E]
+    xs = xt[order // K]                                  # [T*K, D]
+    if layer is None:
+        params, layer = {k: (v[None] if k in EXPERT_WEIGHTS else v)
+                         for k, v in params.items()}, 0
+    grouped = _grouped_matmul()
+
+    def gmm(lhs, name):
+        return grouped(lhs, params[name], sizes, layer)
+    if cfg.act == "silu":
+        h = jax.nn.silu(gmm(xs, "w_gate")) * gmm(xs, "w_up")
+    else:
+        h = jax.nn.gelu(gmm(xs, "w_up"))
+    y = gmm(h.astype(x.dtype), "w_down")                 # f32 [T*K, D]
+    routed = sizes.sum()
+    # rows past the routed ones belong to no expert: the kernel leaves them
+    # unwritten
+    y = jnp.where((jnp.arange(T * K) < routed)[:, None], y, 0.0)
+    unsort = jnp.zeros_like(order).at[order].set(jnp.arange(T * K))
+    y = y[unsort].reshape(T, K, D)
+    out = (y * gates[..., None]).sum(axis=1).astype(x.dtype)
+    counters = jnp.stack([sizes.max(), routed, (sizes > 0).sum(),
+                          pair_ok.sum() - routed]).astype(jnp.int32)
+    return out.reshape(B, S, D), counters

@@ -12,7 +12,12 @@ Families:
 
 Layer stacks are `lax.scan` over stacked parameters (compile-time O(1) in
 depth — essential for the 40-cell dry-run).  Remat policy wraps the scanned
-layer body.  Every family exposes:
+layer body.  A dense/moe model with a layer pattern (``cfg.layer_pattern``:
+sliding-window and full attention mixed) scans over periods of the pattern,
+each period's layers unrolled; its serving cache holds two kinds of KV:
+the full layers' ``k``/``v`` [n_full, B, S, KH, Dh] and the sliding layers'
+rings ``k_win``/``v_win`` [n_sliding, B, W, KH, Dh], where position p lies
+at p mod W.  Every family exposes:
 
   init(key, cfg)                         -> params
   forward(params, batch, cfg, run)       -> (logits, aux)      # train/prefill
@@ -27,7 +32,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.configs.base import ModelConfig, RunConfig
-from repro.distributed.act_sharding import constrain, current_mesh
+from repro.distributed.act_sharding import constrain, whole_on_one_tpu
 from repro.kernels import ops
 
 from . import layers as L
@@ -50,13 +55,7 @@ def _stacked_attn(run: RunConfig, cache_shape):
     place.  Elsewhere — a mesh that splits the cache, whose softmax over a
     split sequence GSPMD reduces, or off the TPU — the jnp read slices the
     layer out first."""
-    mesh = current_mesh()
-    if mesh is None:
-        whole_on_tpu = jax.default_backend() == "tpu"
-    else:
-        whole_on_tpu = (mesh.size == 1
-                        and mesh.devices.flat[0].platform == "tpu")
-    if whole_on_tpu and ops.stacked_fits(cache_shape):
+    if whole_on_one_tpu() and ops.stacked_fits(cache_shape):
         return lambda q, kc_all, vc_all, l, lens: ops.decode_attention_stacked(
             q, kc_all, vc_all, lens, l, interpret=False)
     attn = _dec_attn(run)
@@ -98,6 +97,71 @@ def _angles(cfg: ModelConfig, positions: jax.Array) -> jax.Array | None:
     return L.rope_angles(positions, Dh, cfg.rope_theta)
 
 
+def _ropes(cfg: ModelConfig, positions: jax.Array) -> dict:
+    """Rotary (angles, cos/sin scale) per attention kind: YaRN's on full
+    layers where the config has it, the default on every other."""
+    ang = _angles(cfg, positions)
+    ropes = {"full": (ang, 1.0), "sliding": (ang, 1.0)}
+    if cfg.yarn is not None:
+        Dh = cfg.resolved_head_dim
+        inv = jnp.asarray(L.yarn_inv_freq(Dh, cfg.rope_theta, cfg.yarn))
+        if positions.ndim == 3:
+            positions = positions[:, 0, :]
+        ropes["full"] = (L.rope_angles(positions, Dh, cfg.rope_theta, inv),
+                         cfg.yarn.attention_factor)
+    return ropes
+
+
+def _window(cfg: ModelConfig, kind: str) -> int:
+    """Positions a layer of this kind attends to; 0 for all."""
+    return cfg.sliding_window if kind == "sliding" else 0
+
+
+def _ring_slot(pos: jax.Array, W: int) -> jax.Array:
+    """Where position ``pos`` lies in a ring of W entries."""
+    return pos % W
+
+
+def _periods(layers: dict, period: int) -> dict:
+    """Stacked layer params [L, ...] -> [L / period, period, ...]."""
+    if period == 1:
+        return layers
+    return jax.tree.map(
+        lambda a: a.reshape((a.shape[0] // period, period) + a.shape[1:]),
+        layers)
+
+
+def _member(lps: dict, j: int, period: int) -> dict:
+    """Layer j of one period's params."""
+    return lps if period == 1 else jax.tree.map(lambda a: a[j], lps)
+
+
+def _kind_index(kinds: tuple, j: int, g):
+    """Index of the period-g layer j among all layers of its kind."""
+    per = kinds.count(kinds[j])
+    rank = kinds[:j].count(kinds[j])
+    return g if per == 1 else g * per + rank
+
+
+def _split_experts(layers: dict) -> tuple[dict, dict | None]:
+    """Stacked layer params -> (all but the routed experts' weights, those
+    weights [L, E, ...]).  A scan that took the experts as xs would slice a
+    layer's out for each grouped-matmul call, a copy of every expert
+    matrix; kept whole, the kernel reads layer l where it lies."""
+    mlp = layers["mlp"]
+    if "router" not in mlp:
+        return layers, None
+    experts = {k: v for k, v in mlp.items() if k in X.EXPERT_WEIGHTS}
+    rest = {k: v for k, v in mlp.items() if k not in X.EXPERT_WEIGHTS}
+    return {**layers, "mlp": rest}, experts
+
+
+def _sum_counters(counts: list):
+    """Sum the per-layer MoE counters of one period (None without MoE)."""
+    counts = [c for c in counts if c is not None]
+    return functools.reduce(jnp.add, counts) if counts else None
+
+
 # ===========================================================================
 # Per-layer init/apply for attention-based layers
 # ===========================================================================
@@ -118,10 +182,12 @@ def _init_attn_layer(key: jax.Array, cfg: ModelConfig) -> dict:
 
 
 def _attn_layer_apply(lp: dict, x: jax.Array, cfg: ModelConfig,
-                      run: RunConfig, angles, causal: bool):
+                      run: RunConfig, angles, causal: bool,
+                      rope_scale: float = 1.0, window: int = 0):
     h = L.attention_apply(lp["attn"], L.rmsnorm_apply(lp["ln1"], x, cfg.norm_eps),
                           cfg, angles=angles, causal=causal,
-                          impl=run.attention_impl, chunk=run.attention_chunk)
+                          impl=run.attention_impl, chunk=run.attention_chunk,
+                          rope_scale=rope_scale, window=window)
     x = x + h
     xn = L.rmsnorm_apply(lp["ln2"], x, cfg.norm_eps)
     if "router" in lp["mlp"]:
@@ -161,13 +227,25 @@ def forward_dense(params: dict, batch: dict, cfg: ModelConfig,
     x = constrain(L.embed_apply(params["embed"], tokens, _adtype(cfg),
                                 onehot=cfg.tie_embeddings),
                   "batch", "seq", None)
-    ang = _angles(cfg, positions)
+    ropes = _ropes(cfg, positions)
+    kinds = cfg.layer_period
+    P = len(kinds)
 
-    def layer(x, lp):
-        x, aux = _attn_layer_apply(lp, x, cfg, run, ang, causal=True)
-        return constrain(x, "batch", "seq", None), _aux_vector(aux)
+    def period(x, lps):
+        auxes = []
+        for j, kind in enumerate(kinds):
+            ang, scale = ropes[kind]
+            x, aux = _attn_layer_apply(_member(lps, j, P), x, cfg, run, ang,
+                                       causal=True, rope_scale=scale,
+                                       window=_window(cfg, kind))
+            x = constrain(x, "batch", "seq", None)
+            auxes.append(_aux_vector(aux))
+        return x, auxes[0] if P == 1 else jnp.stack(auxes)
 
-    x, aux_stack = jax.lax.scan(_remat(layer, run), x, params["layers"])
+    x, aux_stack = jax.lax.scan(_remat(period, run), x,
+                                _periods(params["layers"], P))
+    if P > 1:
+        aux_stack = aux_stack.reshape(-1, aux_stack.shape[-1])
     if last_only:
         x = x[:, -1:]
     x = L.rmsnorm_apply(params["final_norm"], x, cfg.norm_eps)
@@ -192,13 +270,30 @@ def _aux_unvector(aux_stack: jax.Array, cfg: ModelConfig) -> dict:
 
 # -- decode -----------------------------------------------------------------
 
+def _n_layers_of(cfg: ModelConfig, kind: str) -> int:
+    kinds = cfg.layer_period
+    return cfg.n_layers // len(kinds) * kinds.count(kind)
+
+
+def ring_size(cfg: ModelConfig, max_seq: int) -> int:
+    """Positions a sliding layer's ring holds: the window, or the whole
+    sequence where that is shorter."""
+    return min(cfg.sliding_window, max_seq)
+
+
 def init_cache_dense(cfg: ModelConfig, batch: int, max_seq: int) -> dict:
     KH, Dh = cfg.n_kv_heads, cfg.resolved_head_dim
-    shape = (cfg.n_layers, batch, max_seq, KH, Dh)
-    return {
+    shape = (_n_layers_of(cfg, "full"), batch, max_seq, KH, Dh)
+    cache = {
         "k": jnp.zeros(shape, _adtype(cfg)),
         "v": jnp.zeros(shape, _adtype(cfg)),
     }
+    n_sliding = _n_layers_of(cfg, "sliding")
+    if n_sliding:
+        ring = (n_sliding, batch, ring_size(cfg, max_seq), KH, Dh)
+        cache["k_win"] = jnp.zeros(ring, _adtype(cfg))
+        cache["v_win"] = jnp.zeros(ring, _adtype(cfg))
+    return cache
 
 
 def _cache_insert(cache: jax.Array, kv: jax.Array, pos: jax.Array):
@@ -243,52 +338,87 @@ def _masked_state(new: jax.Array, old: jax.Array, active) -> jax.Array:
 
 
 def decode_dense(params: dict, cache: dict, batch: dict, cfg: ModelConfig,
-                 run: RunConfig):
+                 run: RunConfig, counters: bool = False):
     """One decode step.  batch: tokens [B,1], seq_lens [B] i32 (tokens
-    already in each slot's cache).  Returns (logits [B, V], new_cache)."""
+    already in each slot's cache).  Returns (logits [B, V], new_cache), and
+    with ``counters`` the step's MoE counters (i32[4], summed over layers;
+    None without experts) after them."""
     tokens = batch["tokens"]
     seq_lens = batch["seq_lens"]                   # [B]: per-slot position
+    active = batch.get("active")
     B = tokens.shape[0]
     x = L.embed_apply(params["embed"], tokens, _adtype(cfg),
                        onehot=cfg.tie_embeddings)
     positions = seq_lens[:, None].astype(jnp.int32)
-    ang = _angles(cfg, positions)
-
-    wpos = _active_pos(batch, cache["k"].shape[2])
+    ropes = _ropes(cfg, positions)
+    kinds = cfg.layer_period
+    P = len(kinds)
     H, Dh = cfg.n_heads, cfg.resolved_head_dim
 
-    def _ffn(x, lp):
+    # Per kind: the stacked caches, each slot's write row (inactive slots
+    # out of range, dropped) and the entries its read covers.  A ring holds
+    # position p at p mod W and is read over min(p + 1, W) entries: the
+    # window, in whatever order, since rope was applied before the write.
+    caches = {"full": (cache["k"], cache["v"])}
+    wpos = {"full": _active_pos(batch, cache["k"].shape[2])}
+    lens = {"full": lambda: seq_lens + 1}
+    if "k_win" in cache:
+        W = cache["k_win"].shape[2]
+        caches["sliding"] = (cache["k_win"], cache["v_win"])
+        ring_pos = _ring_slot(seq_lens, W)
+        wpos["sliding"] = (ring_pos if active is None
+                           else jnp.where(active, ring_pos, W))
+        seen = min(W, _window(cfg, "sliding"))
+        lens["sliding"] = lambda: jnp.minimum(seq_lens + 1, seen)
+    attend = {kind: _stacked_attn(run, kv[0].shape)
+              for kind, kv in caches.items()}
+
+    layers, experts = _split_experts(params["layers"])
+
+    def _ffn(x, lp, layer):
         xn = L.rmsnorm_apply(lp["ln2"], x, cfg.norm_eps)
-        if "router" in lp["mlp"]:
-            h2, _ = X.moe_apply(lp["mlp"], xn, cfg,
-                                group_size=run.moe_group_size)
+        if experts is not None:
+            valid = None if active is None else active[:, None]
+            h2, count = X.moe_dropless({**lp["mlp"], **experts}, xn, cfg,
+                                       valid, layer=layer)
         else:
-            h2 = L.mlp_apply(lp["mlp"], xn, cfg)
-        return x + h2
+            h2, count = L.mlp_apply(lp["mlp"], xn, cfg), None
+        return x + h2, count
 
-    # The stacked cache rides the layer scan's CARRY, which XLA updates in
-    # place: a step writes only the new token's row.  Taking it as xs and
+    # The stacked caches ride the scan's CARRY, which XLA updates in place:
+    # a step writes only the new token's row.  Taking them as xs and
     # returning ys instead copies the whole cache every step.
-    attend = _stacked_attn(run, cache["k"].shape)
+    def period(carry, inputs):
+        x, caches = carry                          # kind -> [L, B, S, KH, Dh]
+        lps, g = inputs
+        counts = []
+        for j, kind in enumerate(kinds):
+            lp, l = _member(lps, j, P), _kind_index(kinds, j, g)
+            kc_all, vc_all = caches[kind]
+            ang, scale = ropes[kind]
+            xn = L.rmsnorm_apply(lp["ln1"], x, cfg.norm_eps)
+            q, k, v = L.attention_qkv(lp["attn"], xn, cfg, ang, scale)
+            kc_all = _cache_insert_at_layer(kc_all, k, l, wpos[kind])
+            vc_all = _cache_insert_at_layer(vc_all, v, l, wpos[kind])
+            o = attend[kind](q[:, 0], kc_all, vc_all, l, lens[kind]())
+            x = x + (o.reshape(B, 1, H * Dh) @ lp["attn"]["wo"])
+            x, count = _ffn(x, lp, g * P + j)
+            caches = {**caches, kind: (kc_all, vc_all)}
+            counts.append(count)
+        return (x, caches), _sum_counters(counts)
 
-    def layer(carry, inputs):
-        x, kc_all, vc_all = carry                  # [L, B, S, KH, Dh]
-        lp, l = inputs
-        xn = L.rmsnorm_apply(lp["ln1"], x, cfg.norm_eps)
-        q, k, v = L.attention_qkv(lp["attn"], xn, cfg, ang)
-        kc_all = _cache_insert_at_layer(kc_all, k, l, wpos)
-        vc_all = _cache_insert_at_layer(vc_all, v, l, wpos)
-        o = attend(q[:, 0], kc_all, vc_all, l, seq_lens + 1)
-        x = x + (o.reshape(B, 1, H * Dh) @ lp["attn"]["wo"])
-        return (_ffn(x, lp), kc_all, vc_all), None
-
-    (x, k_new, v_new), _ = jax.lax.scan(
-        layer, (x, cache["k"], cache["v"]),
-        (params["layers"], jnp.arange(cfg.n_layers)))
+    (x, caches), counts = jax.lax.scan(
+        period, (x, caches),
+        (_periods(layers, P), jnp.arange(cfg.n_layers // P)))
 
     x = L.rmsnorm_apply(params["final_norm"], x, cfg.norm_eps)
     logits = L.unembed_apply(params["embed"], x)[:, 0]
-    return logits, {"k": k_new, "v": v_new}
+    new = {"k": caches["full"][0], "v": caches["full"][1]}
+    if "sliding" in caches:
+        new["k_win"], new["v_win"] = caches["sliding"]
+    if not counters:
+        return logits, new
+    return logits, new, None if counts is None else counts.sum(axis=0)
 
 
 # ===========================================================================
@@ -656,6 +786,9 @@ _FAMILY = {
 
 
 def init(key: jax.Array, cfg: ModelConfig) -> dict:
+    if cfg.layer_pattern:
+        assert cfg.family in ("dense", "moe"), cfg.family
+        assert cfg.n_layers % len(cfg.layer_pattern) == 0, cfg.n_layers
     return _FAMILY[cfg.family][0](key, cfg)
 
 
@@ -669,8 +802,14 @@ def init_cache(cfg: ModelConfig, batch: int, max_seq: int) -> dict:
 
 
 def decode_step(params: dict, cache: dict, batch: dict, cfg: ModelConfig,
-                run: RunConfig):
-    return _FAMILY[cfg.family][3](params, cache, batch, cfg, run)
+                run: RunConfig, counters: bool = False):
+    """(logits, new_cache), and with ``counters`` the step's MoE counters
+    (``moe.COUNTERS``; None for a family without routed experts)."""
+    fn = _FAMILY[cfg.family][3]
+    if fn is decode_dense:
+        return fn(params, cache, batch, cfg, run, counters=counters)
+    out = fn(params, cache, batch, cfg, run)
+    return out + (None,) if counters else out
 
 
 # ===========================================================================
@@ -696,9 +835,26 @@ def _pad_seq(arr: jax.Array, max_seq: int, axis: int = 2) -> jax.Array:
     return jnp.pad(arr, pad)
 
 
+def _ring(kv: jax.Array, n_real: jax.Array, W: int) -> jax.Array:
+    """A prompt's sliding-layer KV [n, B, S, KH, Dh] laid out as the decode
+    ring [n, B, W, KH, Dh]: entry r holds the latest real position p with
+    p = r mod W (zeros where no position is), as decode writes them."""
+    S = kv.shape[2]
+    r = jnp.arange(W)
+    pos = r[None, :] + W * ((n_real[:, None] - 1 - r[None, :]) // W)  # [B,W]
+    ok = pos >= 0
+    idx = jnp.clip(pos, 0, S - 1)[None, :, :, None, None]
+    ring = jnp.take_along_axis(kv, idx, axis=2)
+    return jnp.where(ok[None, :, :, None, None], ring, 0).astype(kv.dtype)
+
+
 def prefill_dense_with_cache(params: dict, batch: dict, cfg: ModelConfig,
-                             run: RunConfig, max_seq: int):
-    """Returns (last_logits [B, V], cache) — dense/moe families."""
+                             run: RunConfig, max_seq: int,
+                             counters: bool = False):
+    """Returns (last_logits [B, V], cache) — dense/moe families — and with
+    ``counters`` the MoE counters (i32[4] summed over layers, or None).
+    Positions past ``last_index`` are padding: experts never see them, and
+    causal attention keeps them out of every real position."""
     tokens = batch["tokens"]
     B, S = tokens.shape
     positions = batch.get("positions")
@@ -706,32 +862,68 @@ def prefill_dense_with_cache(params: dict, batch: dict, cfg: ModelConfig,
         positions = jnp.broadcast_to(jnp.arange(S)[None, :], (B, S))
     x = L.embed_apply(params["embed"], tokens, _adtype(cfg),
                       onehot=cfg.tie_embeddings)
-    ang = _angles(cfg, positions)
+    ropes = _ropes(cfg, positions)
+    kinds = cfg.layer_period
+    P = len(kinds)
     H, KH, Dh = cfg.n_heads, cfg.n_kv_heads, cfg.resolved_head_dim
+    last = batch.get("last_index")
+    n_real = (jnp.full((B,), S, jnp.int32) if last is None
+              else last.astype(jnp.int32) + 1)
 
-    def layer(x, lp):
+    layers, experts = _split_experts(params["layers"])
+
+    def layer(x, lp, kind, index):
+        ang, scale = ropes[kind]
         xn = L.rmsnorm_apply(lp["ln1"], x, cfg.norm_eps)
-        q, k, v = L.attention_qkv(lp["attn"], xn, cfg, ang)
+        q, k, v = L.attention_qkv(lp["attn"], xn, cfg, ang, scale)
         if run.attention_impl == "naive":
-            o = L.naive_attention(q, k, v, causal=True)
+            o = L.naive_attention(q, k, v, causal=True,
+                                  window=_window(cfg, kind))
         else:
             o = L.chunked_attention(q, k, v, causal=True,
-                                    chunk=run.attention_chunk)
+                                    chunk=run.attention_chunk,
+                                    window=_window(cfg, kind))
         x = x + (o.reshape(B, S, H * Dh) @ lp["attn"]["wo"])
         xn = L.rmsnorm_apply(lp["ln2"], x, cfg.norm_eps)
-        if "router" in lp["mlp"]:
-            h2, _ = X.moe_apply(lp["mlp"], xn, cfg,
-                                group_size=run.moe_group_size)
+        if experts is not None:
+            valid = jnp.arange(S)[None, :] < n_real[:, None]
+            h2, count = X.moe_dropless({**lp["mlp"], **experts}, xn, cfg,
+                                       valid, layer=index)
         else:
-            h2 = L.mlp_apply(lp["mlp"], xn, cfg)
-        return x + h2, (k.astype(_adtype(cfg)), v.astype(_adtype(cfg)))
+            h2, count = L.mlp_apply(lp["mlp"], xn, cfg), None
+        return x + h2, (k.astype(_adtype(cfg)), v.astype(_adtype(cfg))), count
 
-    x, (ks, vs) = jax.lax.scan(layer, x, params["layers"])
+    def period(x, inputs):
+        lps, g = inputs
+        kvs = {kind: [] for kind in kinds}
+        counts = []
+        for j, kind in enumerate(kinds):
+            x, kv, count = layer(x, _member(lps, j, P), kind, g * P + j)
+            kvs[kind].append(kv)
+            counts.append(count)
+        if P == 1:
+            kvs = {kinds[0]: kvs[kinds[0]][0]}
+        else:
+            kvs = {kind: tuple(jnp.stack(t) for t in zip(*kv))
+                   for kind, kv in kvs.items()}
+        return x, (kvs, _sum_counters(counts))
+
+    x, (kvs, counts) = jax.lax.scan(
+        period, x, (_periods(layers, P), jnp.arange(cfg.n_layers // P)))
+    if P > 1:   # [periods, per, B, S, ...] -> [layers of the kind, B, S, ...]
+        kvs = jax.tree.map(lambda a: a.reshape((-1,) + a.shape[2:]), kvs)
     x = L.rmsnorm_apply(params["final_norm"], _last_hidden(x, batch),
                         cfg.norm_eps)
     logits = L.unembed_apply(params["embed"], x)[:, 0]
+    ks, vs = kvs["full"]
     cache = {"k": _pad_seq(ks, max_seq), "v": _pad_seq(vs, max_seq)}
-    return logits, cache
+    if "sliding" in kvs:
+        W = ring_size(cfg, max_seq)
+        cache["k_win"] = _ring(kvs["sliding"][0], n_real, W)
+        cache["v_win"] = _ring(kvs["sliding"][1], n_real, W)
+    if not counters:
+        return logits, cache
+    return logits, cache, None if counts is None else counts.sum(axis=0)
 
 
 def prefill_ssm_with_cache(params: dict, batch: dict, cfg: ModelConfig,
@@ -849,6 +1041,11 @@ _PREFILL_CACHE = {
 
 
 def prefill_with_cache(params: dict, batch: dict, cfg: ModelConfig,
-                       run: RunConfig, max_seq: int):
-    """(last_logits [B, V], decode-ready cache) for every family."""
-    return _PREFILL_CACHE[cfg.family](params, batch, cfg, run, max_seq)
+                       run: RunConfig, max_seq: int, counters: bool = False):
+    """(last_logits [B, V], decode-ready cache) for every family, and with
+    ``counters`` the MoE counters as :func:`decode_step` gives them."""
+    fn = _PREFILL_CACHE[cfg.family]
+    if fn is prefill_dense_with_cache:
+        return fn(params, batch, cfg, run, max_seq, counters=counters)
+    out = fn(params, batch, cfg, run, max_seq)
+    return out + (None,) if counters else out

@@ -31,6 +31,7 @@ from repro.core.spans import span
 from repro.core.state import Database
 from repro.distributed.act_sharding import activation_mesh
 from repro.launch.mesh import make_host_mesh
+from repro.models import moe as X
 from repro.models import transformer as T
 
 from .batcher import ContinuousBatcher, Request
@@ -57,6 +58,10 @@ class ServeEngine:
         self.batcher = ContinuousBatcher(n_slots)
         self.slots = SlotAllocator(n_slots, db=db)
         self.params = params
+        # routed experts report per-call counters (moe.COUNTERS), fetched
+        # with the call's tokens or logits in the same transfer
+        self._counted = cfg.moe is not None and cfg.family in ("dense",
+                                                                "moe")
         self.cache = models.init_cache(cfg, n_slots, max_seq)
         self.seq_lens = np.zeros((n_slots,), np.int32)
         self.last_token = np.zeros((n_slots,), np.int32)
@@ -65,18 +70,30 @@ class ServeEngine:
         self.metrics = {"ticks": 0, "prefills": 0, "decode_steps": 0,
                         "tokens_generated": 0, "tick_s": 0.0,
                         "device_wait_s": 0.0, "slot_wait_s": 0.0}
+        if self._counted:
+            self.metrics.update(dict.fromkeys(X.COUNTERS, 0))
+        # this tick's MoE counters: the decode step's, and each prefill's
+        self.step_counters: dict = {"decode": None, "prefill": []}
         self._decode = self._build_decode()
         self._prefill_cache: dict[int, Any] = {}
 
     # ------------------------------------------------------------------ jits
     def _build_decode(self):
         cfg, run, mesh = self.cfg, self.run, self.mesh
+        counted = self._counted
 
         def step(params, cache, batch):
             with activation_mesh(mesh):
-                logits, cache = models.decode_step(params, cache, batch,
-                                                   cfg, run)
-            return jnp.argmax(logits, axis=-1).astype(jnp.int32), cache
+                if counted:
+                    logits, cache, counts = models.decode_step(
+                        params, cache, batch, cfg, run, counters=True)
+                else:
+                    logits, cache = models.decode_step(params, cache, batch,
+                                                       cfg, run)
+            tok = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+            if counted:     # one array, one transfer: tokens then counters
+                tok = jnp.concatenate([tok, counts])
+            return tok, cache
 
         return jax.jit(step, donate_argnums=(1,))
 
@@ -87,7 +104,8 @@ class ServeEngine:
             def prefill(params, batch):
                 with activation_mesh(mesh):
                     return T.prefill_with_cache(params, batch, cfg, run,
-                                                max_seq)
+                                                max_seq,
+                                                counters=self._counted)
 
             self._prefill_cache[plen] = jax.jit(prefill)
         return self._prefill_cache[plen]
@@ -119,16 +137,13 @@ class ServeEngine:
 
     def _do_prefill(self, req: Request) -> None:
         plen = len(req.prompt)
-        if self.cfg.family in ("ssm", "hybrid", "moe"):
-            # ssm/hybrid: recurrent state is taken at the end of the prompt —
-            # padding would roll garbage into it.  moe: pad tokens compete
-            # for expert capacity in the router (26 identical pad
-            # first-choices can fill an expert ahead of a real token's
-            # second choice, changing real logits) -> exact-length prefill.
-            # TODO(production): thread a routing validity mask instead.
+        if self.cfg.family in ("ssm", "hybrid"):
+            # recurrent state is taken at the end of the prompt — padding
+            # would roll garbage into it -> exact-length prefill
             bucket = plen
         else:
-            # causal attention ignores right-padding (masked by seq_lens)
+            # causal attention ignores right-padding (masked by seq_lens),
+            # and experts never see it (prefill's routing validity mask)
             bucket = min(_bucket(plen), self.max_seq)
         toks = np.zeros((1, bucket), np.int32)
         toks[0, :plen] = req.prompt
@@ -138,7 +153,8 @@ class ServeEngine:
             batch["frames"] = jnp.zeros(
                 (1, self.cfg.encoder_seq, self.cfg.d_model),
                 jnp.dtype(self.cfg.activation_dtype))
-        logits, small = self._get_prefill(bucket)(self.params, batch)
+        out = self._get_prefill(bucket)(self.params, batch)
+        logits, small = out[:2]
         # NOTE: right-padded prompts attend causally, so positions < plen are
         # unaffected by the padding; states for SSM families are taken at the
         # bucket end — we therefore bucket SSM prompts exactly.
@@ -148,12 +164,18 @@ class ServeEngine:
         with span("datax.engine.insert"):
             for name, pool in self.cache.items():
                 piece = small[name]
-                if name in ("k", "v", "xk", "xv"):
+                if name in ("k", "v", "k_win", "v_win", "xk", "xv"):
                     self.cache[name] = insert_kv(pool, piece, slot, plen)
                 else:
                     self.cache[name] = insert_state(pool, piece, slot, plen)
         with span("datax.engine.prefill_wait", self.metrics, "device_wait_s"):
-            req.prefill_logits = np.asarray(logits)[0]
+            if self._counted:
+                host, counts = jax.device_get((logits, out[2]))
+                self._count(counts)
+                self.step_counters["prefill"].append(counts)
+            else:
+                host = np.asarray(logits)
+            req.prefill_logits = host[0]
         first = int(req.prefill_logits.argmax())
         req.generated.append(first)
         req.prefill_done = True
@@ -174,6 +196,10 @@ class ServeEngine:
         next_tok, self.cache = self._decode(self.params, self.cache, batch)
         with span("datax.engine.decode_wait", self.metrics, "device_wait_s"):
             next_tok = np.asarray(next_tok)
+        if self._counted:
+            next_tok, counts = next_tok[:self.n_slots], next_tok[self.n_slots:]
+            self._count(counts)
+            self.step_counters["decode"] = counts
         for req in live:
             s = req.slot
             self.seq_lens[s] += 1
@@ -183,8 +209,13 @@ class ServeEngine:
             self.metrics["tokens_generated"] += 1
         self.metrics["decode_steps"] += 1
 
+    def _count(self, counts) -> None:
+        for key, n in zip(X.COUNTERS, counts):
+            self.metrics[key] += int(n)
+
     def tick(self) -> list[Request]:
         """One engine iteration; returns requests finished this tick."""
+        self.step_counters = {"decode": None, "prefill": []}
         with span("datax.engine.tick", self.metrics, "tick_s"):
             with span("datax.engine.plan"):
                 plan = self.batcher.plan_tick(self.slots.n_free)

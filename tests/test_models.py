@@ -80,7 +80,7 @@ def test_full_config_param_count_sane(arch):
         "granite-34b": 34e9, "whisper-large-v3": 1.55e9,
         "qwen2-vl-72b": 72e9, "grok-1-314b": 314e9,
         "granite-moe-3b-a800m": 3.3e9, "mamba2-370m": 0.37e9,
-        "zamba2-2.7b": 2.7e9,
+        "zamba2-2.7b": 2.7e9, "mellum2-12b-a2.5b": 12.15e9,
     }[arch]
     assert 0.75 * expected <= n <= 1.25 * expected, (arch, n, expected)
 

@@ -43,17 +43,16 @@ def test_engine_matches_full_forward_greedy(arch):
             b["frames"] = jnp.zeros((1, cfg.encoder_seq, cfg.d_model))
         return b
 
-    def ref_next_full_forward(toks):
+    def ref_logits_full_forward(toks):
         logits, _ = models.forward(params, fwd_batch(toks), cfg, RUN)
-        return int(jnp.argmax(logits[0, -1]))
+        return np.asarray(logits[0, -1])
 
-    def ref_next_decode(state, toks):
-        """Token-by-token decode reference (B=1) — required for MoE:
-        capacity-based routing is group-size dependent, so a full forward
-        (one group of len(toks) tokens, drops possible) legitimately
-        differs from decode (one token, never drops).  This is the
-        standard capacity-MoE train/inference routing gap, not an engine
-        bug; the decode reference shares the engine's routing regime."""
+    def ref_logits_decode(state, toks):
+        """Token-by-token decode reference (B=1) — required for MoE: the
+        full forward is the training path, whose capacity routing is
+        group-size dependent (one group of len(toks) tokens, drops
+        possible), while serving routes dropless, one token at a time
+        here and the prompt padded to its bucket in the engine."""
         cache, pos = state
         lg = None
         while pos < len(toks):
@@ -62,17 +61,22 @@ def test_engine_matches_full_forward_greedy(arch):
             lg, cache = models.decode_step(params, cache, batch, cfg, RUN)
             pos += 1
         state[0], state[1] = cache, pos
-        return int(jnp.argmax(lg[0]))
+        return np.asarray(lg[0])
 
     for rid, prompt in prompts.items():
-        gen = next(r for r in done if r.request_id == rid).generated
+        req = next(r for r in done if r.request_id == rid)
+        gen = req.generated
         toks = list(prompt)
         dec_state = [models.init_cache(cfg, 1, 64), 0]
         for step in range(5):
             if cfg.family == "moe":
-                nxt = ref_next_decode(dec_state, toks)
+                ref = ref_logits_decode(dec_state, toks)
             else:
-                nxt = ref_next_full_forward(toks)
+                ref = ref_logits_full_forward(toks)
+            if step == 0:     # the engine's prefill logits, bucketed prompt
+                np.testing.assert_allclose(req.prefill_logits, ref,
+                                           rtol=1e-4, atol=1e-5)
+            nxt = int(np.argmax(ref))
             assert gen[step] == nxt, (rid, step, gen)
             toks.append(nxt)
 

@@ -70,6 +70,16 @@ _KERNELS = {
          ((32,), jnp.float32), (1, 2048, 1, 128), (1, 2048, 1, 128)]),
     "rmsnorm": (lambda x, w: ops.rmsnorm(x, w, interpret=False),
                 [(2048, 5120), (5120,)]),
+    # mellum2-12b-a2.5b's experts: a decode step's 512 routed rows, and a
+    # 6,144-token prefill's, against layer 3 of an 8-layer stack
+    "expert_gmm_decode": (
+        lambda x, w, n: ops.expert_gmm(x, w, n, jnp.int32(3),
+                                       interpret=False),
+        [(512, 2304), (8, 64, 2304, 896), ((64,), jnp.int32)]),
+    "expert_gmm_prefill": (
+        lambda x, w, n: ops.expert_gmm(x, w, n, jnp.int32(3),
+                                       interpret=False),
+        [(49152, 896), (8, 64, 896, 2304), ((64,), jnp.int32)]),
 }
 
 
@@ -183,3 +193,65 @@ def test_dense_decode_step_reads_and_writes_cache_in_place(one_chip):
               and (ty == whole or ty in layer)]
     assert not copies, copies
     assert "tpu_custom_call" in hlo
+
+
+def test_swa_moe_decode_step_reads_weights_and_caches_in_place(one_chip):
+    """mellum2-12b-a2.5b's decode step at its widths (two periods: 6
+    sliding and 2 full layers, 64 slots, 8,192 positions, bf16 caches
+    donated) copies neither cache nor any expert weights: no top-level
+    copy, dynamic-slice or dynamic-update-slice produces the full cache,
+    the window rings, one layer of either, or a layer's (or a period's)
+    expert matrices.  The grouped-matmul kernel reads each layer of the
+    stacked experts where it lies, under its own name."""
+    import dataclasses
+
+    import numpy as np
+    from jax.sharding import Mesh
+
+    from repro import models
+    from repro.configs import get_config
+    from repro.configs.base import RunConfig
+    from repro.distributed.act_sharding import activation_mesh
+
+    cfg = dataclasses.replace(get_config("mellum2-12b-a2.5b"), n_layers=8)
+    run = RunConfig(attention_impl="chunked", attention_chunk=256,
+                    remat="none")
+    B, S = 64, 8192
+    mesh = Mesh(np.array(one_chip._device_assignment).reshape(1, 1),
+                ("data", "model"))
+
+    def step(params, cache, batch):
+        with activation_mesh(mesh):
+            logits, cache, counts = models.decode_step(
+                params, cache, batch, cfg, run, counters=True)
+        return jnp.concatenate([jnp.argmax(logits, axis=-1), counts]), cache
+
+    def specs(tree):
+        return jax.tree.map(lambda a: _spec(one_chip, a.shape, a.dtype), tree)
+
+    params = specs(jax.eval_shape(lambda: models.init(jax.random.PRNGKey(0),
+                                                      cfg)))
+    cache = specs(jax.eval_shape(lambda: models.init_cache(cfg, B, S)))
+    batch = {"tokens": _spec(one_chip, (B, 1), jnp.int32),
+             "seq_lens": _spec(one_chip, (B,), jnp.int32),
+             "active": _spec(one_chip, (B,), jnp.bool_)}
+    compiled = jax.jit(step, donate_argnums=(1,)).lower(
+        params, cache, batch).compile()
+    hlo = compiled.as_text()
+    big = {f"bf16[2,{B},{S},4,128]", f"bf16[{B},{S},4,128]",
+           f"bf16[1,{B},{S},4,128]", f"bf16[6,{B},1024,4,128]",
+           f"bf16[{B},1024,4,128]", f"bf16[1,{B},1024,4,128]",
+           "bf16[64,2304,896]", "bf16[64,896,2304]",
+           "bf16[4,64,2304,896]", "bf16[4,64,896,2304]",
+           "bf16[1,64,2304,896]", "bf16[1,64,896,2304]"}
+    copies = [(op, ty) for op, ty in _top_level_ops(hlo)
+              if op in ("copy", "dynamic-slice", "dynamic-update-slice")
+              and ty in big]
+    assert not copies, copies
+    kernels = [line.split(" = ")[0].strip() for line in hlo.splitlines()
+               if 'custom_call_target="tpu_custom_call"' in line]
+    # three grouped matmuls per layer of the unrolled period, named for
+    # the trace; one decode-attention read per layer
+    assert sum("expert_gmm" in k for k in kernels) == 3 * 4, kernels
+    # temporaries stay small: a copy of a period's experts would be 3.2 GB
+    assert compiled.memory_analysis().temp_size_in_bytes < 0.5e9
