@@ -543,8 +543,22 @@ def _resident_burst(rows: Sequence[Any], pad_to: int):
     return dev
 
 
+class _Staging(dict):
+    """A fused unit's kept host buffers (field -> array), which each batched
+    burst stacks into (:func:`_to_device_batched`).  The CPU backend uploads
+    an aligned host array by aliasing it, and the next burst rewrites the
+    buffer, so there ``copy_upload`` is set and each upload copies the
+    buffer first: a CPU unit gains nothing from staging."""
+
+    def __init__(self):
+        import jax
+        super().__init__()
+        self.copy_upload = jax.default_backend() == "cpu"
+
+
 def _to_device_batched(payloads: Sequence[Mapping[str, Any]],
-                       pad_to: int, stats: dict | None = None) -> dict:
+                       pad_to: int, staging: _Staging,
+                       stats: dict | None = None) -> dict:
     """Stack N payloads field-wise into one leading-batch-dim device payload.
 
     Raises TypeError on heterogeneous field sets, non-numeric fields, or
@@ -557,19 +571,28 @@ def _to_device_batched(payloads: Sequence[Mapping[str, Any]],
     stack + transfer entirely and reuse the upstream device array
     (counted in ``stats['resident_links']`` when a stats dict is given).
     With ``stats``, the stack and the upload add their seconds to
-    ``stack_s`` and ``upload_s``."""
+    ``stack_s`` and ``upload_s``.
+
+    Each field is stacked into the leading ``pad_to`` rows of its buffer
+    in ``staging`` instead of a fresh array, whose pages would be mapped,
+    faulted in and unmapped again on every burst once it passes the
+    allocator's mmap threshold.  A field's buffer is replaced when its row
+    shape or dtype changes or a deeper pad needs more rows
+    (``stats['stage_allocs']``); a burst stacked wholly into buffers it
+    found counts in ``stats['stage_reuses']``.  The next burst overwrites
+    the buffer, so the upload never aliases it, and the caller must wait
+    for this burst's uploads before it stacks the next one.  Every field
+    is checked before any buffer is written."""
     import jax.numpy as jnp
     keys = payloads[0].keys()
     for p in payloads[1:]:
         if p.keys() != keys:
             raise TypeError("burst payloads carry different field sets")
-    out = {}
+    fields = {}
     for k in keys:
         resident = _resident_burst([p[k] for p in payloads], pad_to)
         if resident is not None:
-            out[k] = resident
-            if stats is not None:
-                stats["resident_links"] += 1
+            fields[k] = resident
             continue
         rows = []
         for p in payloads:
@@ -585,12 +608,34 @@ def _to_device_batched(payloads: Sequence[Mapping[str, Any]],
         if any(r.shape != first.shape or r.dtype != first.dtype
                for r in rows[1:]):
             raise TypeError(f"field {k!r}: ragged shapes/dtypes across burst")
+        fields[k] = rows
+    out = {}
+    allocs = stacked_fields = 0
+    for k, rows in fields.items():
+        if not isinstance(rows, list):          # an intact resident burst
+            out[k] = rows
+            if stats is not None:
+                stats["resident_links"] += 1
+            continue
         with span("datax.fused.stack", stats, "stack_s"):
             if len(rows) < pad_to:
                 rows.extend(rows[-1:] * (pad_to - len(rows)))
-            stacked = np.stack(rows)
+            first, buf = rows[0], staging.get(k)
+            if buf is None or len(buf) < pad_to \
+                    or buf.shape[1:] != first.shape \
+                    or buf.dtype != first.dtype:
+                buf = staging[k] = np.empty((pad_to, *first.shape),
+                                            first.dtype)
+                allocs += 1
+            stacked = np.stack(rows, out=buf[:pad_to])
+            stacked_fields += 1
         with span("datax.fused.upload", stats, "upload_s"):
-            out[k] = jnp.asarray(stacked)
+            out[k] = jnp.asarray(stacked.copy() if staging.copy_upload
+                                 else stacked)
+    if stats is not None and stacked_fields:
+        stats["stage_allocs"] += allocs
+        if not allocs:
+            stats["stage_reuses"] += 1
     return out
 
 
@@ -656,7 +701,8 @@ def make_fused_logic(stages: Sequence[FusedStage],
     ``max_batch`` of its own) and a ``stats`` counter dict
     (``device_fallbacks`` / ``device_demotions`` / ``batched_bursts`` /
     ``batched_msgs`` / ``sharded_bursts`` / ``sharded_retired`` /
-    ``resident_links`` / ``mesh_devices`` / ``max_batch_current``).
+    ``resident_links`` / ``stage_reuses`` / ``stage_allocs`` /
+    ``mesh_devices`` / ``max_batch_current``).
     ``resident=True`` marks a segment whose exit feeds another fused
     segment: its array outputs stay device-resident
     (:class:`ResidentArray`) for the linked hop.
@@ -685,7 +731,7 @@ def make_fused_logic(stages: Sequence[FusedStage],
         # the other counters join below
         stats = {"stack_s": 0.0, "upload_s": 0.0, "place_s": 0.0,
                  "program_s": 0.0, "download_s": 0.0, "unstack_s": 0.0}
-        program = batched_program = mesh = None
+        program = batched_program = mesh = staging = None
         sprog = {"fn": None}  # sharded program; retired on lowering failure
         if jax_available() and _want_jit() \
                 and all(st.pure_fn is not None for st in stages):
@@ -693,6 +739,7 @@ def make_fused_logic(stages: Sequence[FusedStage],
             chain = [(st.kind, st.pure_fn) for st in stages]
             program = jit_chain(chain)
             batched_program = jit_chain_batched(chain)
+            staging = _Staging()  # host buffers the bursts stack into
             mesh = fusion_mesh()
             if mesh is not None:
                 from ..distributed.sharding import burst_spec
@@ -724,6 +771,7 @@ def make_fused_logic(stages: Sequence[FusedStage],
                       "device_demotions": 0, "batched_bursts": 0,
                       "batched_msgs": 0, "sharded_bursts": 0,
                       "sharded_retired": 0, "resident_links": 0,
+                      "stage_reuses": 0, "stage_allocs": 0,
                       "mesh_devices": ndev, "max_batch_current": tune["cur"]})
 
         # A failing device program must not become host execution unseen:
@@ -823,7 +871,8 @@ def make_fused_logic(stages: Sequence[FusedStage],
                 pad_to = _round_up_pow2(len(payloads))
                 t0 = drained()
                 try:
-                    dev = _to_device_batched(payloads, pad_to, stats)
+                    dev = _to_device_batched(payloads, pad_to, staging,
+                                             stats)
                 except Exception:
                     # conversion = payload problem (ragged shapes, mixed
                     # dtypes, non-numeric or unconvertible values): burst-
@@ -847,6 +896,9 @@ def make_fused_logic(stages: Sequence[FusedStage],
                                     sharded = None
                             if sharded is None:
                                 out, keep = batched_program(dev)
+                            # the next burst rewrites the staging buffers
+                            # only after this wait: the program has read
+                            # its uploads by then
                             keep = np.asarray(keep)
                     except Exception as e:
                         demote(e)
@@ -892,11 +944,20 @@ def make_fused_logic(stages: Sequence[FusedStage],
                     # out of the latency EWMA.  The batched program warms at
                     # the canonical (full) burst size — the steady-state
                     # shape under backlog — and the sharded lowering warms
-                    # alongside it when the mesh divides that shape.
+                    # alongside it when the mesh divides that shape.  The
+                    # burst is stacked into the kept buffers, so that their
+                    # allocation is set-up too: it counts in stage_allocs,
+                    # its seconds and a reuse do not (only bursts count).
                     run_device(zeros)
                     if batched_program is not None and canonical > 1:
-                        dev = _to_device_batched([zeros, zeros], canonical)
-                        batched_program(dev)
+                        spent = {k: stats[k] for k in
+                                 ("stack_s", "upload_s", "stage_reuses")}
+                        dev = _to_device_batched([zeros, zeros], canonical,
+                                                 staging, stats)
+                        stats.update(spent)
+                        # wait on keep as a burst does: the first burst
+                        # rewrites the buffers after this
+                        np.asarray(batched_program(dev)[1])
                         if sprog["fn"] is not None and canonical % ndev == 0:
                             placed = stats["place_s"]
                             try:

@@ -307,12 +307,16 @@ class Sidecar:
                 # mesh): how many devices the unit's mesh spans (1 = no
                 # mesh), how many bursts ran SPMD-partitioned across it,
                 # how many device buffers were reused across a linked
-                # exit/entry pair instead of re-uploading from host, and
-                # the autotuned burst ceiling currently in force
+                # exit/entry pair instead of re-uploading from host, how
+                # many bursts were stacked into kept host buffers and how
+                # many such buffers were allocated, and the autotuned
+                # burst ceiling currently in force
                 "mesh_devices": int(stats.get("mesh_devices", 1)),
                 "sharded_bursts": int(stats.get("sharded_bursts", 0)),
                 "sharded_retired": int(stats.get("sharded_retired", 0)),
                 "resident_links": int(stats.get("resident_links", 0)),
+                "stage_reuses": int(stats.get("stage_reuses", 0)),
+                "stage_allocs": int(stats.get("stage_allocs", 0)),
                 "max_batch_current": int(stats.get("max_batch_current", 0)),
                 # durability surface: log catalogs per durable subject,
                 # replay progress of this instance's subscriptions, and the

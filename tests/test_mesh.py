@@ -22,7 +22,7 @@ import jax.numpy as jnp  # noqa: E402
 from repro.core import ShardSpec, StreamSchema  # noqa: E402
 from repro.core import fusion  # noqa: E402
 from repro.core.fusion import (AUTOTUNE_STREAK, FusedStage,  # noqa: E402
-                               ResidentArray, _resident_burst,
+                               ResidentArray, _resident_burst, _Staging,
                                _to_device_batched, make_fused_logic)
 from repro.core.sdk import LogicContext  # noqa: E402
 from repro.distributed.sharding import burst_spec  # noqa: E402
@@ -183,7 +183,7 @@ def test_to_device_batched_reuses_resident(jit_always):
     payloads = [{"x": ResidentArray.wrap(np.asarray(dev[i]), dev, i)}
                 for i in range(4)]
     stats = {"resident_links": 0}
-    out = _to_device_batched(payloads, 4, stats)
+    out = _to_device_batched(payloads, 4, _Staging(), stats)
     assert out["x"] is dev
     assert stats["resident_links"] == 1
 
@@ -208,6 +208,163 @@ def test_unlinked_segments_emit_plain_arrays(jit_always):
     proc = _fused_process(resident=False)
     out = proc.process_batch("s", _payloads(4))
     assert not any(isinstance(p["x"], ResidentArray) for p in out)
+
+
+# ---------------------------------------------------------------------------
+# Kept staging buffers: each burst stacks into the unit's host buffer
+# ---------------------------------------------------------------------------
+
+FRAME = (6, 8)
+
+
+def _exact_process(monkeypatch, jit=True, resident=False, stages="chain"):
+    """A fused unit over exact float32 stages (gain, a brightness filter,
+    black level), so that every path must agree bit for bit; ``stages=
+    "filter"`` keeps the filter alone, whose output is its input."""
+    monkeypatch.setenv("DATAX_FUSION_JIT", "always" if jit else "never")
+    chain = [("map", lambda p: {"x": p["x"] * 2.0}),
+             ("filter", lambda p: p["x"].max() > 5.0),
+             ("map", lambda p: {"x": p["x"] - 1.0})]
+    if stages == "filter":
+        chain = chain[1:2]
+    built = []
+    for i, (kind, fn) in enumerate(chain):
+        def factory(ctx, fn=fn, kind=kind):
+            if kind == "filter":
+                return lambda stream, payload: payload if fn(payload) \
+                    else None
+            return lambda stream, payload: fn(payload)
+        built.append(FusedStage(au_name=f"au{i}", stream_name=f"s{i}",
+                                factory=factory, config={}, kind=kind,
+                                pure_fn=fn))
+    schema = StreamSchema.device(x=(FRAME, "float32"))
+    ctx = LogicContext({}, db=None, instance_id="test")
+    return make_fused_logic(built, schema, max_batch=64,
+                            resident=resident)(ctx)
+
+
+def _frames(n, seed):
+    """Frames at four exposures, so that the filter keeps about half."""
+    rng = np.random.default_rng(seed)
+    return [{"x": rng.standard_normal(FRAME, dtype=np.float32)
+             * np.float32((i % 4 + 1) / 2)} for i in range(n)]
+
+
+def _stage_stats():
+    return {"stack_s": 0.0, "upload_s": 0.0, "stage_allocs": 0,
+            "stage_reuses": 0}
+
+
+def test_staged_bursts_match_host_chain_bit_for_bit(monkeypatch):
+    host = _exact_process(monkeypatch, jit=False)
+    proc = _exact_process(monkeypatch)
+    for seed in (10, 11):                  # two full bursts, new frames each
+        burst = _frames(8, seed)
+        got = proc.process_batch("s", burst)
+        want = host.process_batch("s", burst)
+        assert [g is None for g in got] == [w is None for w in want]
+        assert any(g is None for g in got) and any(g is not None
+                                                   for g in got)
+        for g, w in zip(got, want):
+            if g is not None:
+                assert g["x"].dtype == w["x"].dtype
+                assert np.array_equal(g["x"], w["x"])
+    assert proc.stats["batched_bursts"] == 2
+    assert (proc.stats["stage_allocs"], proc.stats["stage_reuses"]) == (1, 1)
+
+
+def _aligned(shape, align=64):
+    """An empty float32 array whose data starts on an ``align``-byte
+    boundary: the CPU backend uploads such an array by aliasing it."""
+    n = int(np.prod(shape)) * 4
+    raw = np.empty(n + align, np.uint8)
+    off = -raw.ctypes.data % align
+    return raw[off:off + n].view(np.float32).reshape(shape)
+
+
+def test_upload_from_a_kept_buffer_is_a_copy(jit_always):
+    staging, stats = _Staging(), _stage_stats()
+    staging["x"] = _aligned((8, *FRAME))
+    first, second = _frames(8, 20), _frames(8, 21)
+    up = _to_device_batched(first, 8, staging, stats)
+    assert stats["stage_reuses"] == 1        # the aligned buffer was used
+    jax.block_until_ready(up)         # the wait process_batch makes
+    _to_device_batched(second, 8, staging, stats)    # rewrites the buffer
+    assert np.array_equal(staging["x"], np.stack([p["x"] for p in second]))
+    assert np.array_equal(np.asarray(up["x"]),
+                          np.stack([p["x"] for p in first]))
+
+
+@pytest.mark.parametrize("stages", ["filter", "chain"])
+def test_resident_burst_outlives_the_next_stack(monkeypatch, stages):
+    """A device burst kept as ResidentArray rows (an output the filter
+    forwards from its input, or one the chain computed) still holds its
+    own frames after the next burst is stacked into the kept buffer."""
+    proc = _exact_process(monkeypatch, resident=True, stages=stages)
+    bright = [{"x": p["x"] * np.float32(10)} for p in _frames(8, 30)]
+    kept = proc.process_batch("s", bright)           # every frame passes
+    want = [np.array(p["x"]) for p in kept]
+    proc.process_batch("s", _frames(8, 31))
+    assert proc.stats["stage_reuses"] == 1
+    dev = kept[0]["x"]._datax_dev
+    assert np.array_equal(np.asarray(dev), np.stack(want))
+    # the linked hop re-enters the first burst's device array as it was
+    downstream = _exact_process(monkeypatch, stages="filter")
+    out = downstream.process_batch("s", kept)
+    assert downstream.stats["resident_links"] == 1
+    assert all(np.array_equal(o["x"], w) for o, w in zip(out, want))
+
+
+def test_short_tail_pads_with_its_own_last_row(jit_always):
+    staging, stats = _Staging(), _stage_stats()
+    _to_device_batched(_frames(8, 40), 8, staging, stats)
+    tail = _frames(5, 41)
+    up = np.asarray(_to_device_batched(tail, 8, staging, stats)["x"])
+    want = np.stack([p["x"] for p in tail] + [tail[-1]["x"]] * 3)
+    assert np.array_equal(up, want)
+    assert np.array_equal(staging["x"], want)
+    assert (stats["stage_allocs"], stats["stage_reuses"]) == (1, 1)
+
+
+def test_stage_counters_one_alloc_then_reuse(monkeypatch):
+    proc = _exact_process(monkeypatch)
+    proc.process_batch("s", _frames(8, 50))          # allocates
+    k = 3
+    for seed in range(k):
+        proc.process_batch("s", _frames(8, 51 + seed))
+    assert (proc.stats["stage_allocs"], proc.stats["stage_reuses"]) == (1, k)
+    proc.process_batch("s", _frames(16, 60))         # a deeper pad
+    assert (proc.stats["stage_allocs"], proc.stats["stage_reuses"]) == (2, k)
+    proc.process_batch("s", _frames(4, 61))          # a slice of it
+    proc.process_batch("s", _frames(16, 62))
+    assert (proc.stats["stage_allocs"], proc.stats["stage_reuses"]) \
+        == (2, k + 2)
+    assert proc.stats["batched_bursts"] == k + 4
+
+
+def test_unstackable_burst_leaves_buffers_and_counters(jit_always):
+    staging, stats = _Staging(), _stage_stats()
+    good = [{"a": p["x"], "b": p["x"][0]} for p in _frames(4, 70)]
+    _to_device_batched(good, 4, staging, stats)
+    before = {k: v.copy() for k, v in staging.items()}
+    counted = dict(stats)
+    # field "a" stacks, field "b" is ragged: nothing may be written
+    ragged = [{"a": p["x"] + 100.0, "b": p["x"][0, :n]}
+              for p, n in zip(_frames(4, 71), (8, 7, 8, 8))]
+    with pytest.raises(TypeError):
+        _to_device_batched(ragged, 4, staging, stats)
+    assert stats == counted
+    assert all(np.array_equal(staging[k], before[k]) for k in before)
+
+
+def test_unit_ragged_burst_leaves_stage_counters(monkeypatch):
+    proc = _exact_process(monkeypatch)
+    proc.process_batch("s", _frames(4, 80))
+    ragged = _frames(4, 81)
+    ragged[2] = {"x": np.zeros((3, 8), np.float32)}
+    proc.process_batch("s", ragged)
+    assert proc.stats["unstackable_bursts"] == 1
+    assert (proc.stats["stage_allocs"], proc.stats["stage_reuses"]) == (1, 0)
 
 
 # ---------------------------------------------------------------------------

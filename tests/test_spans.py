@@ -90,6 +90,8 @@ def test_pump_counters_cover_every_burst(jit_always, monkeypatch, mesh):
     m, wall = _pump(64, max_batch=16)
     assert m["mailbox_msgs"] == m["batch_msgs"] == 64
     assert m["batched_bursts"] == 4
+    # the warm-up allocates the kept host buffer, and every burst reuses it
+    assert (m["stage_allocs"], m["stage_reuses"]) == (1, 4)
     assert m["mailbox_wait_s"] > 0
     for key in PUMP_SPANS + FUSED_SPANS:
         assert m[key] > 0, key
