@@ -9,7 +9,7 @@ live: Ctrl-C (or --preempt-at) triggers the preemption path (blocking
 checkpoint, clean exit); re-running the same command resumes.
 
 CPU-sized default (a few M params).  On a real slice, pass --preset 100m
-(or use repro.launch.train with --arch) and scale steps/batch.
+and scale steps/batch.
 
 Run:  PYTHONPATH=src python examples/train_lm.py --steps 100
 """

@@ -131,7 +131,8 @@ def decompress(blob: bytes, *, dictionary: bytes | None = None) -> bytes:
             raise CompressionError(
                 "blob was written with a trained dictionary; supply the "
                 "dictionary bytes it was written with")
-        zd = zstandard.ZstdCompressionDict(dictionary)
+        zd = zstandard.ZstdCompressionDict(
+            dictionary, dict_type=zstandard.DICT_TYPE_FULLDICT)
         try:
             return zstandard.ZstdDecompressor(dict_data=zd).decompress(blob[4:])
         except zstandard.ZstdError as e:

@@ -277,7 +277,7 @@ class DurableLog:
 
         The hot path is a lock + list-append — encoding AND compression
         happen once per segment at roll time (:meth:`Segment.seal`), so a
-        durable publish stays within the CI-gated overhead bound.  (While
+        durable publish adds no per-message encode.  (While
         the dictionary trainer still needs samples, the first
         ``train_dict_after`` appends do encode — a one-time cost.)"""
         with self._lock:

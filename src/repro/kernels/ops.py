@@ -96,8 +96,7 @@ def jit_chain_batched(stages):
     ``keep_mask`` is a ``(N,)`` bool of per-message predicated-filter
     decisions.  Per-message semantics are exactly ``jit_chain``'s — the vmap
     axis only amortizes the per-message XLA dispatch + host<->device sync
-    that dominates short chains under load (the fused_jit vs host gap in
-    BENCH_fusion.json) into one device call per burst.
+    that dominates short chains under load into one device call per burst.
     """
     import jax.numpy as jnp
 

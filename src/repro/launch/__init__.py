@@ -1,5 +1,1 @@
-"""Launchers: mesh definitions, dry-run, train/serve drivers.
-
-NOTE: repro.launch.dryrun sets XLA_FLAGS at import — import it only in a
-fresh process (its __main__ entry).  Everything else here is import-safe.
-"""
+"""Launch helpers: the ``(data, model)`` host mesh and the compile cache."""

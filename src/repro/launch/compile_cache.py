@@ -1,8 +1,8 @@
 """JAX's persistent compilation cache for the entry points.
 
-Entry points (``chip_smoke.py``, ``benchmarks/run.py``, ``launch.serve``,
-``launch.train``) call :func:`enable_compile_cache` at the start of
-``main`` — never at import, so importing a module changes no JAX state.
+The entry point ``chip_smoke.py`` calls :func:`enable_compile_cache` at
+the start of ``main``, never at import, so importing a module changes no
+JAX state.
 """
 from __future__ import annotations
 

@@ -8,9 +8,7 @@ compression hooks stay explicit:
 * gradient compression with error feedback (bf16 cast or int8 EF-SGD-style):
   the distributed-optimization trick — on a real pod the quantized tensor is
   what crosses the wire; here the quantize->dequantize runs inside the step
-  so convergence behaviour is faithfully reproduced, and the collectives
-  benchmark (benchmarks/bench_compression.py) demonstrates the wire-bytes
-  effect via shard_map.
+  so convergence behaviour is faithfully reproduced.
 """
 from __future__ import annotations
 

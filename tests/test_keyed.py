@@ -13,6 +13,7 @@ lives in the stream's shared platform database (``KeyedStore``), so
 autoscaler reads per-partition backlog; fused units inherit the entry
 stream's key policy and barrier on mid-chain keyed consumers.
 """
+import threading
 import time
 
 import pytest
@@ -300,6 +301,47 @@ def test_keyed_stateful_pool_scale_down_keeps_state():
             assert table.get(f"key-{i}")["value"] == rounds
     finally:
         op.shutdown()
+
+
+def test_keyed_stateful_pool_keeps_state_under_stealing():
+    """A keyed fold pool with one straggler member and stealing on: idle
+    members take the straggler's queued partitions, and every key's folds
+    still arrive once each, in order, with the accumulator never reset."""
+    rounds, keys = 4, 64
+    app = App("keyed-steal")
+
+    @app.driver(emits=KV)
+    def src(ctx):
+        return ({"k": f"key-{i}", "v": v}
+                for v in range(rounds) for i in range(keys))
+
+    straggler: dict = {}
+    claim = threading.Lock()
+
+    def fold(acc, payload):
+        # the first member to fold becomes the straggler, 20x slower
+        me = threading.get_ident()
+        with claim:
+            slow = straggler.setdefault("ident", me) == me
+        time.sleep(0.02 if slow else 0.001)
+        return {"n": (acc or {"n": 0})["n"] + 1, "v": payload["v"]}
+
+    (app.sense("events", src).key_by("k")
+        .reduce(fold, name="folds").scaled(instances=4, steal=True))
+    with connect(start=False) as op:
+        app.deploy(op, start_sensors=False)
+        sub = op.subscribe("folds", maxsize=rounds * keys + 8)
+        op.start_pending_sensors()
+        msgs = drain(sub, rounds * keys, timeout=30)
+        stolen = op.bus.stats()["events"]["groups"]["folds"]["stolen"]
+    per_key: dict[str, list[dict]] = {}
+    for m in msgs:
+        per_key.setdefault(m.payload["k"], []).append(m.payload["value"])
+    assert len(per_key) == keys
+    for k, folds in per_key.items():
+        assert [f["v"] for f in folds] == list(range(rounds)), k
+        assert [f["n"] for f in folds] == list(range(1, rounds + 1)), k
+    assert stolen > 0                    # the steal path actually ran
 
 
 def test_sidecar_metrics_surface_lag_and_assignment():

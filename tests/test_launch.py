@@ -1,5 +1,5 @@
-"""Launch-path integration: dry-run cell + elastic re-mesh, in subprocesses
-(device-count changes require fresh jax processes)."""
+"""Launch-path integration: elastic re-mesh in a subprocess (device-count
+changes require fresh jax processes) and the compile cache's placement."""
 import os
 import subprocess
 import sys
@@ -15,29 +15,6 @@ def _run(prog: str, timeout: int = 560):
     return subprocess.run([sys.executable, "-c", textwrap.dedent(prog)],
                           capture_output=True, text=True, timeout=timeout,
                           env=_ENV, cwd=_CWD)
-
-
-@pytest.mark.slow
-def test_dryrun_cell_on_production_mesh(tmp_path):
-    """The flagship deliverable in miniature: one real cell, 512 fake
-    devices, lower+compile+roofline — exactly what dryrun --all does."""
-    prog = f"""
-        import os
-        os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
-        from repro.launch.dryrun import run_cell
-        r = run_cell("mamba2-370m", "decode_32k", multi_pod=False,
-                     out_dir={str(tmp_path)!r})
-        assert r["ok"] and r["flops_per_device"] > 0
-        assert r["wire_bytes_per_device"] >= 0
-        assert r["bottleneck"] in ("compute", "memory", "collective")
-        r2 = run_cell("mamba2-370m", "decode_32k", multi_pod=True,
-                      out_dir={str(tmp_path)!r})
-        assert r2["chips"] == 512 and r["chips"] == 256
-        print("OK", r["bottleneck"], r2["chips"])
-    """
-    res = _run(prog)
-    assert res.returncode == 0, res.stderr[-3000:]
-    assert "OK" in res.stdout
 
 
 @pytest.mark.slow
@@ -87,37 +64,6 @@ def test_elastic_shrink_mesh_resumes_training(tmp_path):
         l1, l2 = float(m1["loss"]), float(m2["loss"])
         assert np.isfinite(l1) and np.isfinite(l2) and l2 < l1 + 1.0
         print("OK", l1, l2)
-    """
-    res = _run(prog)
-    assert res.returncode == 0, res.stderr[-3000:]
-    assert "OK" in res.stdout
-
-
-@pytest.mark.slow
-def test_hlo_collective_parse_multi_device():
-    """Sharded matmul on a (1,4) mesh must surface an all-reduce whose wire
-    bytes match the ring model 2(n-1)/n * bytes."""
-    prog = """
-        import os
-        os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
-        import jax, jax.numpy as jnp
-        from jax.sharding import NamedSharding, PartitionSpec as P
-        from repro.roofline.hlo_cost import analyze_hlo
-        from repro.launch.mesh import make_host_mesh
-
-        mesh = make_host_mesh(1, 4)
-        def f(x, w):
-            return x @ w
-        xs = NamedSharding(mesh, P(None, "model"))
-        ws = NamedSharding(mesh, P("model", None))
-        c = jax.jit(f, in_shardings=(xs, ws),
-                    out_shardings=NamedSharding(mesh, P())).lower(
-            jax.ShapeDtypeStruct((256, 256), jnp.float32),
-            jax.ShapeDtypeStruct((256, 256), jnp.float32)).compile()
-        t = analyze_hlo(c.as_text())
-        expect = 2 * (4 - 1) / 4 * 256 * 256 * 4
-        assert t.collective_bytes.get("all-reduce", 0) == expect, t.collective_bytes
-        print("OK", t.collective_bytes)
     """
     res = _run(prog)
     assert res.returncode == 0, res.stderr[-3000:]

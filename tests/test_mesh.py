@@ -1,6 +1,6 @@
 """Mesh-sharded fused execution (PR 8): sharding, residency, autotune.
 
-The multi-device half runs in a SUBPROCESS (benchmarks/mesh_worker.py with
+The multi-device half runs in a SUBPROCESS (tests/workers/mesh_worker.py with
 ``XLA_FLAGS=--xla_force_host_platform_device_count=4``) because the device
 count must be fixed before jax initializes — this test process already
 imported jax with one device.  The single-process half exercises the same
@@ -30,7 +30,7 @@ from repro.kernels.ops import (jit_chain_batched,  # noqa: E402
                                jit_chain_sharded)
 
 _REPO = pathlib.Path(__file__).resolve().parent.parent
-WORKER = _REPO / "benchmarks" / "mesh_worker.py"
+WORKER = _REPO / "tests" / "workers" / "mesh_worker.py"
 
 D = 16
 
@@ -80,7 +80,7 @@ def test_sharded_execution_on_four_devices():
     env["PYTHONPATH"] = str(_REPO / "src")
     env.pop("DATAX_FUSION_MESH", None)
     proc = subprocess.run(
-        [sys.executable, str(WORKER), "--rounds", "2"],
+        [sys.executable, str(WORKER)],
         env=env, cwd=str(_REPO), capture_output=True, text=True, timeout=570)
     assert proc.returncode == 0, proc.stderr
     data = json.loads(proc.stdout.strip().splitlines()[-1])

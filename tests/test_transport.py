@@ -2,17 +2,14 @@
 
 The in-process tests drive client and server through loopback sockets inside
 one interpreter (fast, deterministic); the acceptance test at the bottom
-spawns REAL worker processes via ``benchmarks/transport_worker.py`` and kills
+spawns REAL worker processes via ``tests/workers/transport_worker.py`` and kills
 one mid-stream, asserting the ISSUE's zero-loss / zero-double-delivery /
 zero-ordering-violation bar across the re-home.
 """
 from __future__ import annotations
 
-import pathlib
 import socket
 import struct
-import sys
-import tempfile
 import time
 
 import numpy as np
@@ -26,12 +23,8 @@ from repro.core.sdk import sdk_entrypoint
 from repro.core.transport import (MAX_FRAME_BYTES, PROTO_VERSION, BusServer,
                                   RemoteBus, TransportError, pack_frame,
                                   read_frame, unpack_frame)
-
-_REPO = pathlib.Path(__file__).resolve().parent.parent
-sys.path.insert(0, str(_REPO))  # for the benchmarks.* helpers
-from benchmarks.bench_transport import (await_members, ordering_violations,
-                                        read_records, spawn_worker,
-                                        wait_for)  # noqa: E402
+from transport_harness import (await_members, ordering_violations,
+                               spawn_worker, wait_for)
 
 SCHEMA = StreamSchema.of(k=FieldSpec("str"), i=FieldSpec("int"))
 

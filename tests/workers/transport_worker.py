@@ -1,4 +1,4 @@
-"""Subprocess consumer for the cross-host transport benchmark and tests.
+"""Subprocess consumer for ``tests/test_transport.py``'s two-process kill.
 
 Runs in its OWN process: connects a :class:`~repro.core.transport.RemoteBus`
 to a served bus, joins a queue group (optionally keyed) and consumes with
@@ -15,17 +15,20 @@ kernel flushes the TCP send buffer before FIN, so every ack sent before the
 exit reaches the server — which is what makes the kill test deterministic:
 the acked set and the written set are identical.
 
-Usage (spawned by bench_transport.py / tests/test_transport.py):
+Usage (spawned through ``tests/transport_harness.spawn_worker``):
 
-    python benchmarks/transport_worker.py --addr 127.0.0.1:47000 \
+    python tests/workers/transport_worker.py --addr 127.0.0.1:47000 \
         --subject ticks --group pool [--key k] --name w1 \
-        --outfile /tmp/w1.log [--kill-after 200] [--batch 32]
+        --outfile /tmp/w1.log [--kill-after 200]
 """
 from __future__ import annotations
 
 import argparse
 import os
 import sys
+
+BATCH = 32          # max messages pulled (and acked) per loop
+IDLE_EXIT_S = 30.0  # clean exit after this many idle seconds
 
 
 def main() -> int:
@@ -41,17 +44,6 @@ def main() -> int:
                     help="records land here as 'k,i' lines, one per message")
     ap.add_argument("--kill-after", type=int, default=None,
                     help="os._exit after this many acked messages (crash sim)")
-    ap.add_argument("--batch", type=int, default=32,
-                    help="max messages pulled (and acked) per loop")
-    ap.add_argument("--idle-exit", type=float, default=30.0,
-                    help="clean exit after this many idle seconds")
-    ap.add_argument("--no-fsync", action="store_true",
-                    help="skip the per-batch fsync (pure-throughput phases "
-                         "where the record file is not the recovery effect)")
-    ap.add_argument("--steal", action="store_true",
-                    help="join with steal=True (pull-based work stealing)")
-    ap.add_argument("--slow-ms", type=float, default=0.0,
-                    help="per-message service time (straggler simulation)")
     args = ap.parse_args()
 
     from repro.core.delivery import Group, Keyed
@@ -60,33 +52,28 @@ def main() -> int:
 
     bus = RemoteBus(args.addr, peer=args.name, connect_timeout=10.0)
     token = bus.issue_token(args.name, [args.subject])
-    policy = (Keyed(args.group, args.key, steal=args.steal) if args.key
-              else Group(args.group, steal=args.steal))
+    policy = Keyed(args.group, args.key) if args.key else Group(args.group)
     sub = bus.subscribe(args.subject, token=token, policy=policy,
                         name=args.name, auto_ack=False)
     consumed = 0
     last_msg = time.monotonic()
-    # block-buffered: the explicit flush (+fsync) before each ack is the
-    # effect-then-acknowledge barrier; line buffering would add a syscall
-    # per message and throttle the coalesced-frame drain being measured
+    # block-buffered: the explicit flush + fsync before each ack is the
+    # effect-then-acknowledge barrier
     with open(args.outfile, "a") as out:
         while True:
-            msgs = sub.next_batch(args.batch, timeout=0.2)
+            msgs = sub.next_batch(BATCH, timeout=0.2)
             if not msgs:
                 if sub.closed:
                     return 3  # connection dropped / subject closed
-                if time.monotonic() - last_msg > args.idle_exit:
+                if time.monotonic() - last_msg > IDLE_EXIT_S:
                     bus.close()
                     return 0
                 continue
             last_msg = time.monotonic()
             for m in msgs:
-                if args.slow_ms:
-                    time.sleep(args.slow_ms / 1000.0)
                 out.write(f"{m.payload['k']},{m.payload['i']}\n")
             out.flush()
-            if not args.no_fsync:
-                os.fsync(out.fileno())
+            os.fsync(out.fileno())
             sub.ack(len(msgs))          # effect recorded -> acknowledge
             consumed += len(msgs)
             if args.kill_after is not None and consumed >= args.kill_after:
